@@ -50,4 +50,6 @@ def main(fast: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     print("\n".join(main()))

@@ -77,12 +77,12 @@ def measure(devices: int, fast: bool) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.dedup import ShardedDedup, ShardedDedupConfig
 
     assert len(jax.devices()) == devices, (len(jax.devices()), devices)
     n = 1 << (18 if fast else 19)
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys = jnp.asarray(np.random.default_rng(9).integers(
         0, 1 << 21, n).astype(np.uint32))
     out = {"devices": devices, "n": n, "batch": 16384}
@@ -123,14 +123,14 @@ def measure_parity(devices: int, backend: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.core import DedupConfig
     from repro.data.streams import zipf_range_stream
     from repro.dedup import ShardedDedup, ShardedDedupConfig
 
     assert len(jax.devices()) == devices, (len(jax.devices()), devices)
     n, batch, mem, nb = 1 << 12, 512, 1 << 15, 8
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys, _ = zipf_range_stream(n, universe=1 << 11, a=1.2, seed=11)
     jkeys = jnp.asarray(keys)
     out = {"devices": devices, "backend": backend, "n": n, "batch": batch}
@@ -181,6 +181,7 @@ def _worker_main(argv) -> int:
 def _spawn(devices: int, fast: bool, extra=()) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices; never the chip
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     cmd = ([sys.executable, "-m", "benchmarks.pipeline_throughput",
@@ -314,6 +315,8 @@ def main(fast: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     if "--worker" in sys.argv:
         raise SystemExit(_worker_main(sys.argv[1:]))
     print("\n".join(main(fast="--fast" in sys.argv)))

@@ -92,4 +92,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     main()

@@ -83,8 +83,7 @@ def roofline_terms(rec: dict) -> dict:
         # no-fusion upper bracket, reported alongside.
         bytes_acc = la.get("hbm_bytes_essential", la["hbm_bytes"])
         coll = la["collectives_bytes"].get("total", 0)
-    else:   # legacy records — possibly raw cost_analysis() payloads written
-            # by a drifted dryrun (list-of-dicts on jax 0.4.x); normalize
+    else:   # records without loop-aware terms: the cost_analysis() dict
         cost = normalize_cost_analysis(rec.get("cost"))
         flops = cost.get("flops", 0.0)
         bytes_acc = cost.get("bytes_accessed",
@@ -141,4 +140,6 @@ def main(fast: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     print("\n".join(main()))

@@ -63,4 +63,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     raise SystemExit(main())

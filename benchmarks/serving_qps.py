@@ -262,5 +262,7 @@ def main(fast: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     fast = "--fast" in __import__("sys").argv
     print("\n".join(main(fast=fast)))

@@ -59,14 +59,14 @@ def measure(devices: int, fast: bool = True) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.core import DedupConfig
     from repro.dedup import ShardedDedup, ShardedDedupConfig
 
     assert len(jax.devices()) == devices, (len(jax.devices()), devices)
     n = 1 << (18 if fast else 21)
     batch = 8192
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys = np.random.default_rng(9).integers(
         0, n, n).astype(np.uint32)
     jkeys = jnp.asarray(keys)
@@ -116,7 +116,7 @@ def measure_rebalance(devices: int, fast: bool, backend: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.core import DedupConfig
     from repro.data.streams import zipf_range_stream
     from repro.dedup import ShardedDedup, ShardedDedupConfig
@@ -128,7 +128,7 @@ def measure_rebalance(devices: int, fast: bool, backend: str) -> dict:
         n, batch, mem, nb, reps = 1 << 16, 2048, 1 << 18, 16, 3
     else:
         n, batch, mem, nb, reps = 1 << 18, 4096, 1 << 20, 32, 3
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys, _ = zipf_range_stream(n, universe=max(n // 2, 1 << 10), a=1.2,
                                 seed=11)
     jkeys = jnp.asarray(keys)
@@ -185,6 +185,7 @@ def _worker_main(argv) -> int:
 def _spawn(devices: int, fast: bool, extra=()) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices; never the chip
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     cmd = ([sys.executable, "-m", "benchmarks.sharded_scaling",
@@ -329,6 +330,8 @@ def main(fast: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compat import configure_compile_cache
+    configure_compile_cache()
     if "--worker" in sys.argv:
         raise SystemExit(_worker_main(sys.argv[1:]))
     fast = "--fast" in sys.argv

@@ -8,20 +8,22 @@ the stream, routes keys to their owner shard, and the ensemble behaves
 bit-identically to one filter with the aggregate memory. The whole stream is
 ingested with ONE dispatch — ``ShardedDedup.run_stream`` scans the
 shard-mapped step over batches with the sharded state donated in place — and
-all version-sensitive jax surfaces go through ``repro.compat``, so this runs
-on the pinned jax 0.4.x and on newer releases alike. Run on a real pod, the
+all version-sensitive jax surfaces go through ``repro.compat``. The eight
+devices are simulated on the host CPU (``JAX_PLATFORMS=cpu``), so the
+example never contends for an accelerator. Run on a real pod, the
 same code spans (pod, data, model) = 512 chips — see repro/launch/dryrun.py
 for the compile-level proof.
 """
 
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
-from repro.compat import set_mesh                             # noqa: E402
+from repro.compat import make_mesh, set_mesh                  # noqa: E402
 from repro.core import Dedup, DedupConfig                     # noqa: E402
 from repro.dedup import (ShardedDedup, ShardedDedupConfig,    # noqa: E402
                          StreamMetrics, truth_from_stream)
@@ -30,7 +32,7 @@ BATCH = 8192
 STEPS = 40
 MEMORY = 1 << 20
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 print(f"mesh: {dict(mesh.shape)} -> {len(jax.devices())} devices")
 
 cfg = DedupConfig.for_variant("rlbsbf", memory_bits=MEMORY, batch_size=BATCH)

@@ -66,10 +66,10 @@ def main(argv=None) -> int:
 
     report = compat.jax_api_report()
     print(f"check_env: jax {report['jax_version']}")
-    print(f"  shard_map        : "
-          f"{'jax.shard_map' if report['native_shard_map'] else 'jax.experimental.shard_map' if report['shard_map'] else 'MISSING'}")
-    print(f"  ambient mesh     : "
-          f"{'jax.set_mesh / use_mesh' if report['set_mesh'] else 'none (0.4.x explicit-mesh path — OK)'}")
+    if report["jax_version"] != report["supported_jax"]:
+        print(f"  WARNING: this repo runs on jax {report['supported_jax']}")
+    print(f"  shard_map        : {'ok' if report['shard_map'] else 'MISSING'}")
+    print(f"  set_mesh         : {'ok' if report['set_mesh'] else 'MISSING'}")
     print(f"  make_mesh        : {'ok' if report['make_mesh'] else 'MISSING'}")
     print(f"  all_to_all       : {'ok' if report['all_to_all'] else 'MISSING'}")
     print(f"  ppermute         : {'ok' if report['ppermute'] else 'MISSING'}")
@@ -107,9 +107,8 @@ def main(argv=None) -> int:
         print("check_env: FAIL — the installed jax lacks required APIs:")
         for name in missing:
             print(f"  - {name}: needed by {_DEPENDENTS.get(name, '(core)')}")
-        print("  Install a jax with these surfaces (>= 0.4.30 works; the "
-              "container pins 0.4.37) — repro.compat adapts the spelling, "
-              "but cannot conjure a missing primitive.")
+        print(f"  Install jax/jaxlib {compat.SUPPORTED_JAX}, the one JAX "
+              f"this repo runs on.")
         return 1
     print("check_env: OK — repro.compat can satisfy every required surface")
     return 0

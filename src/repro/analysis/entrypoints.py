@@ -29,6 +29,7 @@ import numpy as np
 from ..core.config import DedupConfig
 from ..core.sketch import SKETCHES
 from ..core.state import init_state
+from ..compat import make_mesh
 
 # canonical sweep sizes: small enough to compile fast, large enough that
 # the filter (W words / s cells) sits well above every batch-event buffer
@@ -189,7 +190,7 @@ def sharded_stream_entry(*, pipeline: bool, rebalance_buckets: int = 0,
 
     def make_sd():
         from ..dedup.sharded import ShardedDedup, ShardedDedupConfig
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         scfg = ShardedDedupConfig(base=base, pipeline=pipeline)
         return ShardedDedup(scfg, mesh)
 

@@ -75,16 +75,38 @@ _HLO_DTYPE = {
 }
 
 
+# "%name = s32[2,1024]{1,0} op(" definitions and "(param_0.9: s32[2,32768],"
+# computation parameters: the shape of every named value, for HLO text that
+# prints operands by name only ("reduce-window(%param_0.9, %param_1.3)")
+_DEF_RE = re.compile(r"%([\w.\-]+)\s*=\s*\w+\[([0-9,]*)\]")
+_PARAM_SIG_RE = re.compile(r"([\w.\-]+):\s*\w+\[([0-9,]*)\]")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
 def reduce_operand_dims(hlo: str) -> List[int]:
     """Every dimension of every operand of every reduce-class op in the HLO
-    text (the generalized ``tests/test_hlo_step.py`` helper)."""
+    text (the generalized ``tests/test_hlo_step.py`` helper). Operands are
+    read from inline types where the text prints them, and otherwise looked
+    up by name among the module's definitions and computation parameters
+    (the XLA of jax 0.9.0 prints names only)."""
+    shapes = {}
+    for line in hlo.splitlines():
+        for name, shape in _DEF_RE.findall(line):
+            shapes[name] = shape
+        if "->" in line and line.rstrip().endswith("{"):
+            for name, shape in _PARAM_SIG_RE.findall(line.split("->", 1)[0]):
+                shapes[name] = shape
     dims: List[int] = []
     for line in hlo.splitlines():
-        if _REDUCE_RE.search(line):
-            call = line.split("reduce", 1)[1]
-            for shape in _SHAPE_RE.findall(call):
-                if shape:
-                    dims.extend(int(d) for d in shape.split(","))
+        m = _REDUCE_RE.search(line)
+        if not m:
+            continue
+        call = line[m.end():].split(")", 1)[0]
+        found = _SHAPE_RE.findall(call)
+        found += [shapes.get(n, "") for n in _OPERAND_RE.findall(call)]
+        for shape in found:
+            if shape:
+                dims.extend(int(d) for d in shape.split(","))
     return dims
 
 

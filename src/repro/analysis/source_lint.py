@@ -52,6 +52,7 @@ HOT_MODULES = (
 DRIFTED_SUFFIXES = (
     "jax.experimental.shard_map", "shard_map.shard_map", "jax.shard_map",
     "jax.set_mesh", "jax.sharding.set_mesh", "jax.sharding.use_mesh",
+    "jax.make_mesh",
     "lax.ppermute", "lax.pshuffle",
     ".cost_analysis", "._cache_size",
 )

@@ -1,139 +1,107 @@
-"""Version-adaptive JAX compatibility layer — the single choke point.
+"""JAX compatibility layer — the single choke point.
 
-The container pins jax 0.4.37, but the distributed subsystem (and several
-launch/analysis call sites) were written against newer API names. Policy
-(DESIGN.md §4): every version-sensitive jax surface is wrapped HERE, call
-sites import from ``repro.compat`` and never spell the raw API, so a jax
-upgrade (or downgrade) only ever edits this one module. Resolution happens
-at *call* time, not import time, so tests can monkeypatch either branch and
+The repo runs on one JAX: jax/jaxlib 0.9.0 (libtpu 0.0.34 on the chip).
+Policy (DESIGN.md §4): every version-sensitive jax surface is wrapped HERE,
+call sites import from ``repro.compat`` and never spell the raw API, so a
+jax upgrade only ever edits this one module. Resolution happens at *call*
+time, not import time, so tests can monkeypatch a surface and
 ``scripts/check_env.py`` can report exactly what the installed jax provides.
 
 Wrapped surfaces:
 
-  * ``shard_map``          — ``jax.shard_map`` (0.6+) vs
-                             ``jax.experimental.shard_map.shard_map`` (0.4.x),
-                             with the replication-check flag translated
-                             between its two names (``check_vma`` on new jax,
-                             ``check_rep`` on 0.4.x/0.5.x).
-  * ``set_mesh``           — context manager over ``jax.set_mesh`` (0.6+) /
-                             ``jax.sharding.use_mesh`` (0.5.x) / no-op on
-                             0.4.x, where ``shard_map``/``jit`` take the mesh
-                             explicitly and no ambient mesh exists.
-  * ``cost_analysis_dict`` — ``compiled.cost_analysis()`` returned a
-                             one-element list of dicts on 0.4.x and a plain
-                             dict on newer jax; normalize to a dict.
+  * ``make_mesh``          — ``jax.make_mesh`` with every axis ``Auto``.
+                             jax 0.9.0 defaults to ``Explicit`` axes, under
+                             which the sharded scan's reshapes raise
+                             ``ShardingTypeError``; this repo's shard_map
+                             bodies assume the Auto (GSPMD) semantics.
+  * ``configure_compile_cache`` — JAX's persistent compilation cache for
+                             the chip entry points (never called by tests).
+  * ``shard_map``          — ``jax.shard_map`` with the replication-check
+                             flag ``check_vma``.
+  * ``set_mesh``           — ``jax.set_mesh`` as a context manager.
+  * ``cost_analysis_dict`` — ``compiled.cost_analysis()`` as a plain dict
+                             (``None`` from a backend becomes ``{}``).
   * ``ppermute``           — the static collective-permute primitive behind
-                             the elastic shard rebalance (DESIGN §4.4):
-                             ``jax.lax.ppermute`` today, with the historical
-                             ``pshuffle``/future renames resolved at call
-                             time like every other surface here.
+                             the elastic shard rebalance (DESIGN §4.4).
+  * ``jit_cache_size``     — the private compiled-specialization count.
 """
 
 from __future__ import annotations
 
-import contextlib
-import inspect
-from typing import Any, Callable, Dict, Mapping, Optional
+import os
+import pathlib
+from typing import Any, Callable, Dict, Mapping, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+SUPPORTED_JAX = "0.9.0"
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+# ------------------------------------------------------------ compile cache
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at ``<repo>/.jax_cache``
+    — a fixed path, since the directory is part of what a later run must
+    find again. Called by ``chip_smoke.py`` and the benchmark entry points;
+    tests never call it."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# ---------------------------------------------------------------- make_mesh
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types on every axis — the mesh
+    every sharded path of this repo is written against. ``devices`` (e.g.
+    a described topology's devices) is passed through."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)
 
 
 # ---------------------------------------------------------------- shard_map
 def _resolve_shard_map() -> Callable:
-    """The installed jax's shard_map callable, wherever it lives."""
-    fn = getattr(jax, "shard_map", None)                 # jax >= 0.6
-    if fn is None:
-        try:                                             # jax 0.4.x / 0.5.x
-            from jax.experimental.shard_map import shard_map as fn
-        except ImportError:                              # pragma: no cover
-            fn = None
-    if fn is None:                                       # pragma: no cover
-        raise NotImplementedError(
-            "installed jax has neither jax.shard_map nor "
-            "jax.experimental.shard_map — run scripts/check_env.py")
-    return fn
+    """The installed jax's shard_map callable."""
+    return jax.shard_map
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None, **kwargs) -> Callable:
-    """``jax.shard_map`` across jax versions.
-
-    ``check_vma`` follows the newest spelling; on jax that predates it the
-    flag is passed as ``check_rep`` (same meaning: verify the out_specs'
-    claimed replication). ``None`` leaves the library default in place.
-    """
-    fn = _resolve_shard_map()
+    """``jax.shard_map``; ``check_vma=None`` leaves the library default."""
     if check_vma is not None:
-        params = inspect.signature(fn).parameters
-        if "check_vma" in params:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_vma
-        # else: the knob disappeared — it only gates a debug check, drop it
+        kwargs["check_vma"] = check_vma
+    fn = _resolve_shard_map()
     return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 # ----------------------------------------------------------------- set_mesh
-@contextlib.contextmanager
 def set_mesh(mesh):
-    """Make ``mesh`` the ambient mesh where the installed jax has one.
-
-    jax 0.6+ exposes ``jax.set_mesh`` (usable as a context manager), 0.5.x
-    has ``jax.sharding.use_mesh``, and 0.4.x has neither — there every
-    consumer in this repo (``shard_map``, ``NamedSharding``) is handed the
-    mesh explicitly, so the 0.4.x branch is a documented no-op rather than a
-    missing feature.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        getter = (getattr(jax, "get_mesh", None)
-                  or getattr(jax.sharding, "get_mesh", None))
-        prev = getter() if getter is not None else None
-        ctx = setter(mesh)
-        if hasattr(ctx, "__enter__"):
-            with ctx:
-                yield mesh
-            return
-        # plain global setter: restore the PREVIOUS mesh on exit (never pass
-        # None — real jax.set_mesh rejects it); without a getter the mesh
-        # stays set, which nested users must tolerate anyway
-        try:
-            yield mesh
-        finally:
-            if getter is not None and prev is not None:
-                setter(prev)
-        return
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        with use_mesh(mesh):
-            yield mesh
-        return
-    yield mesh                                           # jax 0.4.x
+    """Context manager making ``mesh`` the ambient mesh (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 # ----------------------------------------------------------------- ppermute
 def _resolve_ppermute() -> Callable:
-    """The installed jax's collective-permute callable, whatever its name.
-    (``pshuffle`` is NOT an acceptable fallback — its ``perm`` is a source
-    list, a different convention from ppermute's (source, dest) pairs.)"""
-    for name in ("ppermute", "collective_permute"):
-        fn = getattr(jax.lax, name, None)
-        if fn is not None:
-            return fn
-    raise NotImplementedError(                           # pragma: no cover
-        "installed jax.lax has no ppermute/collective_permute — run "
-        "scripts/check_env.py")
+    """The installed jax's collective-permute callable."""
+    return jax.lax.ppermute
 
 
 def ppermute(x: Any, axis_name, perm) -> Any:
-    """``jax.lax.ppermute`` across jax versions: send each device's value of
-    ``x`` (a pytree) along the STATIC ``perm`` schedule of (source, dest)
-    pairs over ``axis_name`` (a name or tuple of names, linearized like
+    """``jax.lax.ppermute``: send each device's value of ``x`` (a pytree)
+    along the STATIC ``perm`` schedule of (source, dest) pairs over
+    ``axis_name`` (a name or tuple of names, linearized like
     ``all_to_all``). The rebalance permute (DESIGN §4.4) builds its dynamic
     re-partition out of a full ring of these static sends — the permutation
     XLA compiles never depends on runtime load."""
-    fn = _resolve_ppermute()
-    return fn(x, axis_name, perm)
+    return _resolve_ppermute()(x, axis_name, perm)
 
 
 # ------------------------------------------------------------ jit internals
@@ -142,7 +110,7 @@ def jit_cache_size(fn) -> int:
 
     jax only exposes this through the private ``_cache_size`` method; the
     no-recompile regression tests depend on it, so the private spelling
-    lives HERE (pinned-jax policy) rather than at every call site."""
+    lives HERE rather than at every call site."""
     probe = getattr(fn, "_cache_size", None)
     if probe is None:                                    # pragma: no cover
         raise NotImplementedError(
@@ -153,25 +121,18 @@ def jit_cache_size(fn) -> int:
 
 # ------------------------------------------------------------ cost analysis
 def normalize_cost_analysis(ca: Any) -> Dict[str, float]:
-    """Normalize a raw ``cost_analysis()`` return to one flat dict.
-
-    jax 0.4.x returns a one-element list of dicts (one per partition of the
-    executable), newer jax returns the dict directly, and some backends
-    return ``None``. Also accepts already-normalized dicts, so persisted
-    records (experiments/dryrun.json) written by either vintage load
-    uniformly (see benchmarks/roofline.py).
-    """
+    """Normalize a raw ``cost_analysis()`` return (a dict, or ``None`` on a
+    backend without one) to a flat dict; already-normalized records
+    (experiments/dryrun.json, benchmarks/roofline.py) pass through."""
     if ca is None:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, Mapping):                      # pragma: no cover
         raise TypeError(f"unexpected cost_analysis payload: {type(ca)!r}")
     return dict(ca)
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a plain dict on every jax version."""
+    """``compiled.cost_analysis()`` as a plain dict."""
     return normalize_cost_analysis(compiled.cost_analysis())
 
 
@@ -179,22 +140,13 @@ def cost_analysis_dict(compiled) -> Dict[str, float]:
 def jax_api_report() -> Dict[str, Any]:
     """What the installed jax provides, surface by surface — consumed by
     ``scripts/check_env.py`` (fail fast) and useful for bug reports."""
-    report: Dict[str, Any] = {"jax_version": jax.__version__}
-    try:
-        _resolve_shard_map()
-        report["shard_map"] = True
-    except NotImplementedError:
-        report["shard_map"] = False
-    report["native_shard_map"] = hasattr(jax, "shard_map")
-    report["set_mesh"] = (hasattr(jax, "set_mesh")
-                          or hasattr(jax.sharding, "use_mesh"))
+    report: Dict[str, Any] = {"jax_version": jax.__version__,
+                              "supported_jax": SUPPORTED_JAX}
+    report["shard_map"] = hasattr(jax, "shard_map")
+    report["set_mesh"] = hasattr(jax, "set_mesh")
     report["make_mesh"] = hasattr(jax, "make_mesh")
     report["all_to_all"] = hasattr(jax.lax, "all_to_all")
-    try:
-        _resolve_ppermute()
-        report["ppermute"] = True
-    except NotImplementedError:                          # pragma: no cover
-        report["ppermute"] = False
+    report["ppermute"] = hasattr(jax.lax, "ppermute")
     try:
         from jax.experimental import pallas  # noqa: F401
         report["pallas"] = True
@@ -203,27 +155,11 @@ def jax_api_report() -> Dict[str, Any]:
     return report
 
 
-REQUIRED_APIS = ("shard_map", "set_mesh_or_explicit", "make_mesh",
-                 "all_to_all", "ppermute", "pallas")
+REQUIRED_APIS = ("shard_map", "set_mesh", "make_mesh", "all_to_all",
+                 "ppermute", "pallas")
 
 
 def missing_apis() -> list:
-    """Names from ``REQUIRED_APIS`` the installed jax cannot satisfy.
-
-    ``set_mesh_or_explicit`` is satisfiable on EVERY supported jax: either an
-    ambient-mesh API exists, or the 0.4.x explicit-mesh path applies — it is
-    listed so the check's output names the contract, not just the symbols.
-    """
+    """Names from ``REQUIRED_APIS`` the installed jax cannot satisfy."""
     r = jax_api_report()
-    missing = []
-    if not r["shard_map"]:
-        missing.append("shard_map")
-    if not r["make_mesh"]:
-        missing.append("make_mesh")
-    if not r["all_to_all"]:
-        missing.append("all_to_all")
-    if not r["ppermute"]:
-        missing.append("ppermute")
-    if not r["pallas"]:
-        missing.append("pallas")
-    return missing
+    return [name for name in REQUIRED_APIS if not r[name]]

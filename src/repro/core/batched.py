@@ -27,9 +27,9 @@ Every variant is described by a ``SketchSpec`` (``core.sketch``, DESIGN.md
 plane count d. ``make_batched_step`` generates the jnp step from the spec
 (``make_templated_step`` below — one factory for both the bitset and counter
 families), and ``repro.kernels.fused_template.make_fused_step`` generates
-the single-launch Pallas step from the SAME spec — the decision functions
-and word algebra are traced inside the kernel, so the two backends are
-bit-identical by construction (DESIGN.md §3.4/§3.6/§3.8).
+the Pallas step as THIS step with its filter-sized update handed to one
+kernel (the ``apply`` hook), so the two backends are bit-identical by
+construction (DESIGN.md §3.4/§3.6/§3.8).
 """
 
 from __future__ import annotations
@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import DedupConfig
-from .hashing import derive_seeds, hash_positions
+from .hashing import derive_seeds, hash_positions, uniform_positions
 from .packed import (clamped_run_counts, count_planes_from_sorted,
                      delta_from_sorted_positions, planes_nonzero,
                      planes_saturating_add, planes_saturating_sub,
@@ -98,7 +98,7 @@ def draw_randomness(cfg: DedupConfig, rng: jax.Array, b: int
     """
     k, s = cfg.k, cfg.s
     rng, r_ins, r_del, r_aux = jax.random.split(rng, 4)
-    del_pos = jax.random.randint(r_del, (b, k), 0, s, dtype=jnp.int32)
+    del_pos = uniform_positions(r_del, (b, k), s)
     u_bern = (jax.random.uniform(r_ins, (b,))
               if cfg.variant == "rsbf" else jnp.zeros((b,), jnp.float32))
     u_aux = (jax.random.uniform(r_aux, (b, k))
@@ -109,8 +109,8 @@ def draw_randomness(cfg: DedupConfig, rng: jax.Array, b: int
 
 
 def make_decision_fn(cfg: DedupConfig):
-    """Pure per-variant decision logic, shared by the jnp step and the fused
-    Pallas kernel (traced inside the kernel — single source of truth).
+    """Pure per-variant decision logic of the bitset family — one function
+    for both backends (the fused step runs it in XLA before its kernel).
 
     decide(vals, valid, seen, i_t, load, rnd) ->
         (dup (B,) bool, insert (B,) bool, del_mask (B, k) bool)
@@ -118,8 +118,6 @@ def make_decision_fn(cfg: DedupConfig):
     s, k = cfg.s, cfg.k
 
     def decide(vals, valid, seen, i_t, load, rnd: BatchRandomness):
-        # iota, not jnp.arange: this traces inside the fused Pallas kernel,
-        # which rejects captured device-array constants
         rows = jax.lax.iota(jnp.int32, k)
         b = valid.shape[0]
         filter_dup = jnp.all(vals == 1, axis=1)
@@ -156,15 +154,19 @@ def make_decision_fn(cfg: DedupConfig):
 
 def sorted_enabled_positions(pos: jnp.ndarray, mask: jnp.ndarray,
                              sentinel: int) -> jnp.ndarray:
-    """(B, k) positions + enable mask -> (k, B) ascending per row; disabled
-    lanes carry ``sentinel`` (> any real position) and sort to the end.
+    """(B, k) positions + enable mask -> (k, B) uint32 ascending per row;
+    disabled lanes carry ``sentinel`` (> any real position) and sort to the
+    end. uint32, so the sentinel 32·W = 2^31 of the paper's 512 MB filter
+    (s = 2^31) fits; real positions are < 2^31 and sort as they would in
+    int32.
 
     A *value-free* single-operand sort — everything downstream (delta words,
     pre-values, first-occurrence flags) is recomputed from the sorted
     positions instead of permuted alongside them, because multi-operand
     sorts hit XLA's slow comparator path (DESIGN.md §3.1/§3.2).
     """
-    return jnp.sort(jnp.where(mask, pos, sentinel).T, axis=-1)
+    return jnp.sort(jnp.where(mask, pos.astype(jnp.uint32),
+                              jnp.uint32(sentinel)).T, axis=-1)
 
 
 def load_delta_from_sorted(spi: jnp.ndarray, pre_i: jnp.ndarray,
@@ -172,8 +174,8 @@ def load_delta_from_sorted(spi: jnp.ndarray, pre_i: jnp.ndarray,
                            post_d: jnp.ndarray, s: int) -> jnp.ndarray:
     """Exact per-row load delta of the batched update R = (A & ~D) | I.
 
-    spi / spd: (k, B) *sorted* insert / delete positions (sentinel >= s for
-    disabled lanes); pre_*: the corresponding PRE-update bit values {0,1};
+    spi / spd: (k, B) *sorted* uint32 insert / delete positions (sentinel
+    >= s for disabled lanes); pre_*: the corresponding PRE-update bit values {0,1};
     post_d: the POST-update bits at the delete positions. Intra-batch
     duplicate positions count once (run heads of the sorted arrays). A bit
     both deleted and inserted nets the insert — since deletes apply before
@@ -182,10 +184,11 @@ def load_delta_from_sorted(spi: jnp.ndarray, pre_i: jnp.ndarray,
     O(B log B) total, no O(s) reduce over the filter (DESIGN.md §3.1).
     """
     gained = jnp.sum(
-        jnp.where(run_heads(spi) & (spi < s), 1 - pre_i.astype(jnp.int32), 0),
+        jnp.where(run_heads(spi) & (spi < jnp.uint32(s)),
+                  1 - pre_i.astype(jnp.int32), 0),
         axis=-1)
     lost = jnp.sum(
-        jnp.where(run_heads(spd) & (spd < s) & (post_d == 0),
+        jnp.where(run_heads(spd) & (spd < jnp.uint32(s)) & (post_d == 0),
                   pre_d.astype(jnp.int32), 0), axis=-1)
     return (gained - lost).astype(jnp.int32)
 
@@ -212,7 +215,7 @@ def draw_sbf_randomness(cfg: DedupConfig, rng: jax.Array, b: int):
     split/draw order is frozen and identical to the dense8 branch (and, at
     b == 1, to the sequential oracle) — part of the determinism contract."""
     rng, r = jax.random.split(rng)
-    start = jax.random.randint(r, (b,), 0, cfg.s, dtype=jnp.int32)
+    start = uniform_positions(r, (b,), cfg.s)
     return rng, start
 
 
@@ -381,7 +384,8 @@ class CounterStepDeltas(NamedTuple):
 
 
 def make_counter_planes_step(cfg: DedupConfig, spec,
-                             params_aware: bool = False) -> BatchedStep:
+                             params_aware: bool = False,
+                             apply=None) -> BatchedStep:
     """The counter-family step generator (DESIGN.md §3.8): one jnp ingest
     step over the (d, W) bit-plane algebra, specialized by a ``SketchSpec``
     — probe op (nonzero bit vs d-bit cell value), decision fn, event-delta
@@ -397,12 +401,17 @@ def make_counter_planes_step(cfg: DedupConfig, spec,
     value-like seams — the cms/hh verdict threshold, the sbf set-to-Max
     ceiling, and the swbf ring-slot advance modulus — leaving every shape
     and every rng draw untouched, so one trace serves all tenants under
-    ``jax.vmap``."""
-    cfg = cfg.validate()
+    ``jax.vmap``.
+
+    ``apply`` replaces the filter-sized update: ``apply(planes (d, W),
+    load, ev, cmax) -> (new planes, new load)``. The fused Pallas step
+    (``kernels.fused_template``) passes its kernel here, so probe, decide,
+    randomness, events and ring are this very code on both backends."""
+    cfg = cfg.validate().check_int32_positions()
     seeds = derive_seeds(cfg.seed, cfg.k, channel=0)
     bseeds = (derive_seeds(cfg.seed, cfg.k, channel=1)
               if cfg.block_bits else None)
-    s, W = cfg.s, cfg.s_words
+    s = cfg.s
     squeeze = cfg.n_planes == 1
     decide = spec.make_decide(cfg)
     events_fn = spec.make_events(cfg)
@@ -428,45 +437,14 @@ def make_counter_planes_step(cfg: DedupConfig, spec,
         else:
             rng, rnd = state.rng, None
         ev = events_fn(state, pos, valid, rnd)
-        new = planes
-        if ev.sub_planes is not None:
-            new = planes_saturating_sub(new, ev.sub_planes)
-        if ev.set_delta is not None:
-            # set-to-Max writes the sketch's counter ceiling (sbf_max), which
-            # may sit below the plane capacity 2^d - 1
-            cmax = tp.max_value if params_aware else cfg.sbf_max
-            new = planes_set_value(new, ev.set_delta, cmax)
-        if ev.add_planes is not None:
-            new = planes_saturating_add(new, ev.add_planes)
-        if cfg.debug_exact_load:
-            load = popcount(planes_nonzero(new)[None])
+        # set-to-Max writes the sketch's counter ceiling (sbf_max), which
+        # may sit below the plane capacity 2^d - 1
+        cmax = tp.max_value if params_aware else cfg.sbf_max
+        if apply is not None:
+            new, load = apply(planes, state.load, ev, cmax)
         else:
-            # exact incremental load (nonzero-cell count, §3.1):
-            #   gained — insert/set cells whose PRE value was zero (their
-            #            head event leaves them nonzero);
-            #   lost   — decremented cells that were nonzero and whose POST
-            #            nonzero bit is clear (decayed to zero, not
-            #            refreshed — inserts apply after decrements, so the
-            #            post bit IS the "was it refreshed" flag).
-            # Each cell counts once (run heads); batch-sized gathers only.
-            new_nz = planes_nonzero(new)
-            sentinel = 32 * W
-
-            def nz_bit(words, sp):
-                got = words[jnp.minimum(sp >> 5, W - 1)]
-                return (got >> (sp & 31).astype(jnp.uint32)) & jnp.uint32(1)
-
-            gained = jnp.sum(ev.ins_heads & (ev.ins_events < sentinel)
-                             & (nz_bit(nzw, ev.ins_events) == 0),
-                             dtype=jnp.int32)
-            if ev.sub_events is None:
-                lost = jnp.int32(0)
-            else:
-                lost = jnp.sum(ev.sub_heads & (ev.sub_events < sentinel)
-                               & (nz_bit(nzw, ev.sub_events) == 1)
-                               & (nz_bit(new_nz, ev.sub_events) == 0),
-                               dtype=jnp.int32)
-            load = state.load + gained - lost
+            new, load = _counter_update(cfg, planes, state.load, ev, cmax,
+                                        nzw)
         bits = new[:, None, :] if not squeeze else new
         ring = state.ring
         if ev.ring_payload is not None:
@@ -480,10 +458,54 @@ def make_counter_planes_step(cfg: DedupConfig, spec,
     return step
 
 
+def _counter_update(cfg: DedupConfig, planes, load, ev, cmax, nzw):
+    """The jnp counter-family update: subtract, then set/add (insertions
+    win), and the §3.1 exact incremental nonzero-cell load."""
+    W = cfg.s_words
+    new = planes
+    if ev.sub_planes is not None:
+        new = planes_saturating_sub(new, ev.sub_planes)
+    if ev.set_delta is not None:
+        new = planes_set_value(new, ev.set_delta, cmax)
+    if ev.add_planes is not None:
+        new = planes_saturating_add(new, ev.add_planes)
+    if cfg.debug_exact_load:
+        load = popcount(planes_nonzero(new)[None])
+    else:
+        # exact incremental load (nonzero-cell count, §3.1):
+        #   gained — insert/set cells whose PRE value was zero (their
+        #            head event leaves them nonzero);
+        #   lost   — decremented cells that were nonzero and whose POST
+        #            nonzero bit is clear (decayed to zero, not
+        #            refreshed — inserts apply after decrements, so the
+        #            post bit IS the "was it refreshed" flag).
+        # Each cell counts once (run heads); batch-sized gathers only.
+        new_nz = planes_nonzero(new)
+        sentinel = 32 * W
+
+        def nz_bit(words, sp):
+            got = words[jnp.minimum(sp >> 5, W - 1)]
+            return (got >> (sp & 31).astype(jnp.uint32)) & jnp.uint32(1)
+
+        gained = jnp.sum(ev.ins_heads & (ev.ins_events < sentinel)
+                         & (nz_bit(nzw, ev.ins_events) == 0),
+                         dtype=jnp.int32)
+        if ev.sub_events is None:
+            lost = jnp.int32(0)
+        else:
+            lost = jnp.sum(ev.sub_heads & (ev.sub_events < sentinel)
+                           & (nz_bit(nzw, ev.sub_events) == 1)
+                           & (nz_bit(new_nz, ev.sub_events) == 0),
+                           dtype=jnp.int32)
+        load = load + gained - lost
+    return new, load
+
+
 def _make_sbf_dense8_step(cfg: DedupConfig) -> BatchedStep:
     """Dense uint8 SBF reference branch — deliberately NOT spec-driven: it
     is the cross-check the plane steps are tested bit-identical against, so
     it keeps its own naive scatter/recount formulation (DESIGN.md §3.6)."""
+    cfg.check_int32_positions()
     seeds = derive_seeds(cfg.seed, cfg.k, channel=0)
     bseeds = (derive_seeds(cfg.seed, cfg.k, channel=1)
               if cfg.block_bits else None)
@@ -515,13 +537,19 @@ def _make_sbf_dense8_step(cfg: DedupConfig) -> BatchedStep:
     return step
 
 
-def make_bitset_step(cfg: DedupConfig, spec) -> BatchedStep:
+def make_bitset_step(cfg: DedupConfig, spec, apply=None) -> BatchedStep:
     """The bitset-family step generator (DESIGN.md §3.1/§3.8): one jnp
     ingest step over the 1-bit R = (A & ~D) | I algebra, specialized by a
     ``SketchSpec`` — the spec supplies the decision fn and the randomness
     draw; probe/scatter/load are the family-shared machinery. rsbf, bsbf,
-    bsbfsd and rlbsbf are all THIS function under different specs."""
-    cfg = cfg.validate()
+    bsbfsd and rlbsbf are all THIS function under different specs.
+
+    ``apply`` (plane layout only) replaces the filter-sized update:
+    ``apply(bits (k, W), load, spi, spd) -> (new bits, new load)`` from the
+    (k, B) sorted uint32 insert/delete positions. The fused Pallas step
+    passes its kernel here, so everything before the update is this very
+    code on both backends."""
+    cfg = cfg.validate().check_int32_positions()
     seeds = derive_seeds(cfg.seed, cfg.k, channel=0)
     bseeds = (derive_seeds(cfg.seed, cfg.k, channel=1)
               if cfg.block_bits else None)
@@ -577,6 +605,11 @@ def make_bitset_step(cfg: DedupConfig, spec) -> BatchedStep:
         ins_mask = jnp.broadcast_to(insert[:, None], (b, k))
         spi = sorted_enabled_positions(pos, ins_mask, sentinel)
         spd = sorted_enabled_positions(rnd.del_pos, del_mask, sentinel)
+        if apply is not None:
+            bits, load = apply(state.bits, state.load, spi, spd)
+            new = FilterState(bits, state.position
+                              + valid.sum(dtype=jnp.int32), load, rng)
+            return new, BatchResult(dup=dup, inserted=insert)
         bits = apply_updates(state.bits, pos, ins_mask, rnd.del_pos, del_mask,
                              spi, spd)
         if cfg.debug_exact_load:

@@ -116,15 +116,15 @@ class DedupConfig:
     backend: str = "jnp"                 # "jnp" | "pallas" — batched-step impl
                                          # (pallas = fused single-launch kernel,
                                          # plane layouts only; DESIGN §3.4/§3.6)
-    kernel_accumulate: bool = False      # pallas counter kernels: scatter the
+    kernel_accumulate: bool = False      # pallas counter kernels: OR the
                                          # per-event probe contributions into
                                          # the VMEM-resident tiles inside the
                                          # kernel instead of consuming (d, W)
                                          # delta planes pre-reduced by XLA
                                          # (bit-identical either way; §3.9).
                                          # A no-op on the jnp backend and for
-                                         # the bitset family, whose kernel is
-                                         # already per-element (chunk_or).
+                                         # the bitset family, whose kernel
+                                         # has one form (XLA delta rows).
     block_bits: int = 0                  # >0: blocked layout, 2^b-bit blocks
                                          # (VMEM-tile locality; DESIGN §3.3)
     delete_set_bits_only: bool = False   # phase-3 RSBF "find a set bit" (scan engine)
@@ -231,6 +231,25 @@ class DedupConfig:
     def rsbf_phase3_start(self) -> int:
         """First stream position where s/i <= p*  (the paper's point ``p``)."""
         return int(math.ceil(self.s / self.p_star))
+
+    def check_int32_positions(self) -> "DedupConfig":
+        """A step engine addresses one filter (one shard) with int32
+        positions: s <= 2^31 for the bitset family, whose sorted positions
+        carry a uint32 sentinel, and 32·W < 2^31 for the counter family,
+        whose int32 event cells carry the sentinel 32·W. Sharding
+        (``ShardedDedup``) divides s, so a larger aggregate filter is
+        served by shards. Checked by the step generators, not by
+        ``validate``: a sharded service's base config may exceed it."""
+        if self.s > 1 << 31:
+            raise ValueError(
+                f"{self.variant}: s = {self.s} positions per filter do not "
+                f"fit int32 — raise k or shard the filter")
+        if self.is_counter and 32 * self.s_words > (1 << 31) - 1:
+            raise ValueError(
+                f"{self.variant}: {self.s} cells per shard — the counter "
+                f"family's int32 event cells need 32·W < 2^31; shard the "
+                f"filter (ShardedDedup) or lower memory_bits")
+        return self
 
     def validate(self) -> "DedupConfig":
         if self.variant not in ALL_VARIANTS:

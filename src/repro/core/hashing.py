@@ -128,6 +128,15 @@ def range_bucket(keys: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
 
 
 def uniform_positions(rng: jax.Array, shape, s: int) -> jnp.ndarray:
-    """Uniform random bit positions in [0, s) — used for the paper's random
-    deletions. Uses randint (unbiased for any s)."""
-    return jax.random.randint(rng, shape, 0, s, dtype=jnp.int32)
+    """Uniform random positions in [0, s) as int32 — the paper's random
+    deletions and SBF's decrement-run starts. Unbiased randint for any s.
+
+    The paper's 512 MB filter has s = 2^31 bits per filter, one past the
+    int32 ``maxval`` randint accepts, so s >= 2^31 draws in uint32 (every
+    value still fits int32 for s <= 2^31, which the step generators
+    check). Below 2^31 the draw is the int32 one, unchanged — the pinned
+    digests depend on it."""
+    if s <= np.iinfo(np.int32).max:
+        return jax.random.randint(rng, shape, 0, s, dtype=jnp.int32)
+    return jax.random.randint(rng, shape, np.uint32(0), np.uint32(s),
+                              dtype=jnp.uint32).astype(jnp.int32)

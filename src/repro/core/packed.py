@@ -226,9 +226,10 @@ def unpack_cells(planes: jnp.ndarray, s: int) -> jnp.ndarray:
 
 def planes_nonzero(planes: jnp.ndarray) -> jnp.ndarray:
     """(d, ..., W) -> (..., W) uint32 word with bit j set iff cell j != 0.
-    Python-unrolled OR — no reduce op over any filter-sized axis."""
+    Python-unrolled OR — no reduce op over any filter-sized axis. A list
+    of d word rows works too."""
     nz = planes[0]
-    for p in range(1, planes.shape[0]):
+    for p in range(1, len(planes)):
         nz = nz | planes[p]
     return nz
 
@@ -354,32 +355,40 @@ def planes_saturating_sub(planes: jnp.ndarray, counts: jnp.ndarray
     bit-planes, each count already clamped into [0, 2^d) (clamping to Max is
     lossless for the saturated result since value <= Max). The final borrow
     word marks cells where count exceeded the value — those saturate to 0.
+    Either operand may also be a list of d word rows (the fused kernel's
+    tiles, which Mosaic cannot stack); the result is then a list too.
     """
-    d = planes.shape[0]
-    assert counts.shape[0] == d, (planes.shape, counts.shape)
+    d = len(planes)
+    assert len(counts) == d, (d, len(counts))
     borrow = jnp.zeros_like(planes[0])
     diffs = []
     for p in range(d):
         a, c = planes[p], counts[p]
         diffs.append(a ^ c ^ borrow)
         borrow = (~a & (c | borrow)) | (c & borrow)
-    return jnp.stack([dp & ~borrow for dp in diffs])
+    return _like(planes, [dp & ~borrow for dp in diffs])
+
+
+def _like(planes, rows):
+    """``rows`` stacked when ``planes`` is an array, a list when a list."""
+    return list(rows) if isinstance(planes, (list, tuple)) else jnp.stack(rows)
 
 
 def planes_saturating_add(planes: jnp.ndarray, addend: jnp.ndarray
                           ) -> jnp.ndarray:
     """Per-cell ``min(value + addend, 2^d - 1)`` as a carry chain of word
     ops (the increment dual of ``planes_saturating_sub``; counting-filter
-    building block). Overflowing cells saturate to the all-ones value."""
-    d = planes.shape[0]
-    assert addend.shape[0] == d, (planes.shape, addend.shape)
+    building block). Overflowing cells saturate to the all-ones value.
+    Lists of word rows are accepted as in ``planes_saturating_sub``."""
+    d = len(planes)
+    assert len(addend) == d, (d, len(addend))
     carry = jnp.zeros_like(planes[0])
     sums = []
     for p in range(d):
         a, c = planes[p], addend[p]
         sums.append(a ^ c ^ carry)
         carry = (a & c) | (a & carry) | (c & carry)
-    return jnp.stack([sp | carry for sp in sums])
+    return _like(planes, [sp | carry for sp in sums])
 
 
 def planes_set_value(planes: jnp.ndarray, delta: jnp.ndarray, value
@@ -394,12 +403,12 @@ def planes_set_value(planes: jnp.ndarray, delta: jnp.ndarray, value
     DESIGN §4.6): ``(A & ~D) | (D & mask_p)`` with ``mask_p`` the all-ones
     word iff value's bit p is set — identical words, data-dependent value."""
     if isinstance(value, (int, np.integer)):
-        return jnp.stack(
-            [(planes[p] | delta) if (int(value) >> p) & 1
-             else (planes[p] & ~delta) for p in range(planes.shape[0])])
+        return _like(planes,
+                     [(planes[p] | delta) if (int(value) >> p) & 1
+                      else (planes[p] & ~delta) for p in range(len(planes))])
     vdyn = jnp.asarray(value, jnp.uint32)
     out = []
-    for p in range(planes.shape[0]):
+    for p in range(len(planes)):
         mask_p = jnp.uint32(0) - ((vdyn >> p) & jnp.uint32(1))
         out.append((planes[p] & ~delta) | (delta & mask_p))
-    return jnp.stack(out)
+    return _like(planes, out)

@@ -47,8 +47,8 @@ class SketchSpec:
                        -> (dup, insert, del_mask)     [``make_decision_fn``]
       counter family: decide(vals, valid, seen) -> dup — ``vals`` is (B, k)
         bool for probe="nonzero", (B, k) int32 cell values for probe="value";
-        written value-dtype-agnostic so the SAME fn traces in the jnp step
-        and inside the Pallas kernel (bit-identity by construction).
+        written value-dtype-agnostic (bool probe bits and int32 cell
+        values read alike); both backends run it in XLA.
     draw(cfg, rng, b) -> (rng, rnd) or None when the sketch is deterministic
       (the rng then threads through the state untouched).
     make_events(cfg) -> events(state, pos, valid, rnd) -> CounterStepDeltas
@@ -70,8 +70,7 @@ class SketchSpec:
 
 # ---------------- counter-family decision fns ---------------------------- //
 # Value-dtype-agnostic on purpose: ``vals != 0`` reads bool probe bits and
-# int32 cell values identically, so one decide serves the jnp step (bool
-# fast path) and the fused kernel (whatever the probe op yields in VMEM).
+# int32 cell values identically, so one decide serves both probe ops.
 
 def _decide_sbf(cfg: DedupConfig):
     def decide(vals, valid, seen):

@@ -229,22 +229,41 @@ class ShardedDedup:
                                       * self.scfg.capacity(local_batch,
                                                            self.mesh))
             kw["event_capacity"] = event_capacity
-        base = init_state(self.local_cfg, seed, **kw)
 
-        def stack(x):
-            return jnp.broadcast_to(x[None], (self.n_shards, *x.shape))
+        def build():
+            base = init_state(self.local_cfg, seed, **kw)
 
-        state = FilterState(
-            bits=stack(base.bits),
-            position=jnp.ones((self.n_shards,), jnp.int32),
-            load=stack(base.load),
-            rng=jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-                base.rng, jnp.arange(self.n_shards)),
-            ring=jax.tree.map(stack, base.ring),   # swbf window ring (§3.7)
-        )
-        return jax.tree.map(
-            lambda x: jax.device_put(x, NamedSharding(
-                self.mesh, P(self.axis, *([None] * (x.ndim - 1))))), state)
+            def stack(x):
+                return jnp.broadcast_to(x[None], (self.n_shards, *x.shape))
+
+            return FilterState(
+                bits=stack(base.bits),
+                position=jnp.ones((self.n_shards,), jnp.int32),
+                load=stack(base.load),
+                rng=jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                    base.rng, jnp.arange(self.n_shards)),
+                ring=jax.tree.map(stack, base.ring),   # swbf ring (§3.7)
+            )
+
+        return self._build_sharded(build)
+
+    def _build_sharded(self, build) -> FilterState:
+        """Run the state builder under ``jax.jit`` with ``out_shardings``:
+        each device materialises only its own shard of the stacked state —
+        nothing filter-sized is ever built on one device and then split.
+        Leaves with a leading shard axis shard over ``mesh_axes``; the
+        elastic router table is replicated."""
+        shaped = jax.eval_shape(build)
+
+        def spec(x):
+            return NamedSharding(self.mesh,
+                                 P(self.axis, *([None] * (x.ndim - 1))))
+
+        shardings = jax.tree.map(spec, shaped._replace(router=None))
+        if shaped.router is not None:
+            shardings = shardings._replace(router=jax.tree.map(
+                lambda _: NamedSharding(self.mesh, P()), shaped.router))
+        return jax.jit(build, out_shardings=shardings)()
 
     def _init_elastic(self, seed, event_capacity, local_batch) -> FilterState:
         """Elastic state (§4.4): leaves carry (n_shards, b_r, ...) — one
@@ -260,30 +279,25 @@ class ShardedDedup:
                 event_capacity = self.scfg.bucket_capacity(
                     local_batch, self.mesh)
             kw["event_capacity"] = event_capacity
-        base = init_state(self.local_cfg, seed, **kw)
 
-        def stack(x):
-            return jnp.broadcast_to(x[None, None], (n, b_r, *x.shape))
+        def build():
+            base = init_state(self.local_cfg, seed, **kw)
 
-        bucket_ids = jnp.arange(nb, dtype=jnp.int32).reshape(n, b_r)
-        state = FilterState(
-            bits=stack(base.bits),
-            position=jnp.ones((n, b_r), jnp.int32),
-            load=stack(base.load),
-            rng=jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(None, 0)),
-                         in_axes=(None, 0))(base.rng, bucket_ids),
-            ring=jax.tree.map(stack, base.ring),
-            router=init_router(nb, n),
-        )
+            def stack(x):
+                return jnp.broadcast_to(x[None, None], (n, b_r, *x.shape))
 
-        def put(x, spec):
-            return jax.device_put(x, NamedSharding(self.mesh, spec))
+            bucket_ids = jnp.arange(nb, dtype=jnp.int32).reshape(n, b_r)
+            return FilterState(
+                bits=stack(base.bits),
+                position=jnp.ones((n, b_r), jnp.int32),
+                load=stack(base.load),
+                rng=jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(None, 0)),
+                             in_axes=(None, 0))(base.rng, bucket_ids),
+                ring=jax.tree.map(stack, base.ring),
+                router=init_router(nb, n),
+            )
 
-        core = jax.tree.map(
-            lambda x: put(x, P(self.axis, *([None] * (x.ndim - 1)))),
-            state._replace(router=None))
-        router = jax.tree.map(lambda x: put(x, P()), state.router)
-        return core._replace(router=router)
+        return self._build_sharded(build)
 
     # -------------------------------------------------------------- //
     def _local_fn(self, cap: int):

@@ -9,9 +9,8 @@ one extra abs-max reduction — the classic 1-bit/8-bit SGD family trick
 accumulating across steps.
 
 These run inside ``shard_map`` data-parallel sections — always entered via
-``repro.compat.shard_map``, which resolves the installed jax's spelling
-(``jax.shard_map`` on 0.6+, ``jax.experimental.shard_map`` on the pinned
-0.4.x) so these helpers never touch a version-sensitive surface directly.
+``repro.compat.shard_map`` (``jax.shard_map``), so these helpers never
+touch a version-sensitive surface directly.
 The pjit train steps use XLA's native reduce-scatter/all-reduce (already
 overlapped by the scheduler), and the examples/tests demonstrate the
 explicit path.

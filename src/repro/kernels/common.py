@@ -13,10 +13,16 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ..core.packed import popcount_words
+from ..launch.hw import VMEM_BYTES
 
 DEFAULT_TILE_W = 512
 DEFAULT_CHUNK_B = 1024
 VMEM_FILTER_BYTES_LIMIT = 8 * 1024 * 1024
+# scoped VMEM the fused kernel may use: the resident set above, its output
+# copy, and the tile and event-chunk temporaries. v5e has 128 MiB of VMEM
+# (launch/hw.py); the compiler's default scope is smaller.
+VMEM_LIMIT_BYTES = 4 * VMEM_FILTER_BYTES_LIMIT
+assert VMEM_LIMIT_BYTES <= VMEM_BYTES
 
 
 def check_vmem_budget(nbytes: int, what: str) -> None:
@@ -44,14 +50,19 @@ def counter_vmem_words(d: int, *, has_sub: bool, set_mode: bool,
                 (d if has_sub else 0) + (1 if set_mode else d))
 
 
-def _event_operand_words(n_events: int, rows: int, chunk_b: int) -> int:
-    """Words of one accumulate-mode event operand pair: the (E,) int32 word
-    index plus the (rows, E) uint32 masks, E padded up to the power-of-two
-    chunk the kernel sweeps (mirrors ``fused_template._event_operands``)."""
+def event_chunk(n_events: int, limit: int = DEFAULT_CHUNK_B) -> int:
+    """Power-of-two event chunk (>= 8) of the accumulate form's tree-OR:
+    the smallest one covering ``min(limit, n_events)``."""
+    return 1 << max(3, min(limit, n_events) - 1).bit_length()
+
+
+def _event_operand_words(n_events: int, rows: int, chunk: int) -> int:
+    """Words of one accumulate-mode event operand pair: the (1, E) int32
+    word index plus the (rows, E) uint32 masks, E padded up to a multiple
+    of ``chunk`` (mirrors ``fused_template._event_operands``)."""
     if n_events <= 0:
         return 0
-    tbc = 1 << max(3, min(chunk_b, n_events) - 1).bit_length()
-    padded = n_events + ((-n_events) % tbc)
+    padded = n_events + ((-n_events) % chunk)
     return (1 + rows) * padded
 
 
@@ -70,17 +81,19 @@ def fused_resident_bytes(cfg, batch_size: int | None = None,
     cfg = cfg.validate()
     w = cfg.s_words
     spec = get_spec(cfg.variant)
+    b = cfg.batch_size if batch_size is None else batch_size
     if spec.family == "bitset":
-        return cfg.k * w * 4
+        # the k filter rows plus the insert and delete delta rows
+        return 3 * cfg.k * w * 4
     d = cfg.n_planes
     set_mode = spec.combine == "set"
     words = counter_vmem_words(d, has_sub=spec.has_sub, set_mode=set_mode,
                                accumulate=cfg.kernel_accumulate)
     ev_words = 0
     if cfg.kernel_accumulate:
-        b = cfg.batch_size if batch_size is None else batch_size
         cap = b if event_capacity is None else event_capacity
-        chunk = DEFAULT_CHUNK_B
+        ins_e = (cap if spec.windowed else b) * cfg.k
+        chunk = event_chunk(ins_e)
         if spec.has_sub:
             # sbf decays B*P random cells; swbf expires one ring slot
             sub_e = (cap * cfg.k if spec.windowed
@@ -88,7 +101,7 @@ def fused_resident_bytes(cfg, batch_size: int | None = None,
             sub_rows = 1 if (set_mode and cfg.sbf_max == 0) else d
             ev_words += _event_operand_words(sub_e, sub_rows, chunk)
         ins_rows = 1 if set_mode else d
-        ev_words += _event_operand_words(b * cfg.k, ins_rows, chunk)
+        ev_words += _event_operand_words(ins_e, ins_rows, chunk)
     return (words * w + ev_words) * 4
 
 
@@ -134,17 +147,3 @@ def chunk_or(w_idx: jnp.ndarray, masks: jnp.ndarray, lane: jnp.ndarray
         x = x[:half] | x[half:]
     return x[0]
 
-
-def probe_all_nonzero(planes_ref, d: int, k: int, iw, im, base):
-    """Fused all-K-cells-nonzero verdict over a (d, 1, W) plane ref: OR of
-    every plane's gathered word, bit test, AND over probes. Kept for the
-    deprecation shims; the template kernels probe per-cell (``fused_template``)
-    so one decision fn serves both backends."""
-    rows = [planes_ref[p, 0, :] for p in range(d)]
-    hit = base
-    for f in range(k):
-        got = rows[0][iw[:, f]]
-        for p in range(1, d):
-            got = got | rows[p][iw[:, f]]
-        hit = hit & ((got & im[:, f]) != 0)
-    return rows, hit

@@ -11,8 +11,7 @@ import warnings
 
 from .common import (DEFAULT_TILE_W, check_vmem_budget,          # noqa: F401
                      largest_tile as _largest_tile,
-                     popcount_sum as _popcount_sum,
-                     probe_all_nonzero as _probe_all_nonzero)
+                     popcount_sum as _popcount_sum)
 from .fused_template import make_fused_step
 
 

@@ -1,117 +1,181 @@
-"""Pallas TPU kernel generator: the fused single-launch ingest step, emitted
-from a ``SketchSpec`` (DESIGN.md §3.4/§3.6/§3.7/§3.8).
+"""Pallas TPU kernel generator: the fused filter sweep of the ingest step,
+emitted from a ``SketchSpec`` (DESIGN.md §3.4/§3.6/§3.7/§3.8).
 
-ONE generator replaces the three hand-written kernels that used to live in
-``fused_step.py`` / ``fused_counter_step.py`` (now deprecation shims). Per
-family it emits one ``pallas_call`` that performs, with the whole filter
-VMEM-resident and written in place (``input_output_aliases``):
+``make_fused_step`` returns the SAME step as the jnp backend
+(``core.batched.make_bitset_step`` / ``make_counter_planes_step``) with one
+seam replaced: the filter-sized update. Hashing, the O(B·k) probe gather,
+the spec's decision fn, the randomness draw, the event sort, the swbf ring
+and position bookkeeping are the jnp step's own code, traced in XLA — so the
+two backends are bit-identical by construction. One ``pallas_call`` then
+sweeps the VMEM-resident filter in tiles of TW words, writes it in place
+(``input_output_aliases``) and counts the exact load delta from the words it
+already holds:
 
-* ``bitset`` (rsbf/bsbf/bsbfsd/rlbsbf — packed (k, W) rows):
-  probe gather -> the spec's decision fn (``make_decision_fn``, traced
-  inside the kernel) -> fused ``(A & ~D) | I`` tile sweep with the exact
-  per-row load delta from the tile's delta words.
-* ``counter`` (sbf/swbf/cms/hh — (d, 1, W) bit-plane cells): probe (nonzero
-  bit, or the full d-bit cell value for the counting sketches) -> the
-  spec's decision fn -> fused subtract-then-(set|add) tile sweep over the
-  event word deltas built OUTSIDE the kernel by the spec's event op
-  (sorting does not belong in a kernel), with the exact nonzero-cell load
-  delta from the tile's pre/post nonzero words.
+* ``bitset`` (rsbf/bsbf/bsbfsd/rlbsbf — packed (k, W) rows): per row,
+  ``(A & ~D) | I`` and ``popcount(I & ~A) - popcount(A & D & ~I)``.
+* ``counter`` (sbf/swbf/cms/hh — d bit-planes of W words): the spec's
+  subtract, then set-to-Max or saturating add, as carry/borrow chains
+  (``planes_saturating_sub/add``, ``planes_set_value``), and the change in
+  nonzero cells.
 
-``cfg.kernel_accumulate`` (DESIGN.md §3.9, off by default) switches the
-counter family's delta operands from pre-reduced (d, W) planes to the
-per-event form: the kernel receives the SORTED event cells (word index +
-head-gated contribution masks, one row per count bit-plane) and
-OR-accumulates them into the VMEM-resident tile directly (``chunk_or``
-tree-OR — heads are unique per cell, so the OR is collision-free), instead
-of XLA scattering the events into filter-sized delta planes first and the
-kernel streaming those planes back in. The event *sort* stays outside
-either way; only the filter-sized reduction moves in. Bit-identical to the
-delta path by construction: the masks are exactly the words the outside
-scatter would have built. The bitset family already works per-event
-(``chunk_or`` below), so the flag is a documented no-op there.
+XLA reduces the batch's events to filter-sized delta rows (the jnp step's
+own ``delta_from_sorted_positions`` / event planes) and the kernel streams
+them beside the filter. For the counter family ``cfg.kernel_accumulate``
+(DESIGN.md §3.9) instead hands the kernel the sorted per-event word indices
+and masks, which it ORs into each tile (``chunk_or``, a tree-OR over
+power-of-two chunks); the sentinel word index W matches no lane. Both forms
+give the same words. The bitset family has the delta-row form only.
 
-Bit-identity with the jnp steps is by construction, not by porting: the
-kernel traces the SAME decision fn and the SAME plane algebra
-(``planes_saturating_sub/add``, ``planes_set_value``) as
-``core.batched.make_templated_step``, and probes in the SAME dtype the jnp
-step feeds its decide (bool for the nonzero probe, int32 cell values for
-the value probe). Engine-side state that is not filter state — the rng
-thread, the swbf ring slot overwrite — stays jnp outside the kernel.
-
-Layout/tiling (DESIGN.md §3.4): the shared ``check_vmem_budget`` guard
-bounds the VMEM-resident working set (filter + event operands) at 8 MiB —
-larger filters shard across devices first (repro.dedup.sharded) — and the
-update sweeps W in tiles of TW <= 512. Off-TPU the kernels run in interpret
-mode and are validated bit-exactly against the jnp steps in
-tests/test_sketch_template.py.
+Mosaic (the TPU kernel compiler) constraints the kernel is written to:
+every ref is 2-D and read or written by row in ``pl.ds`` tiles; loads leave
+the kernel in an SMEM output (VMEM cannot take scalar stores); no gather,
+no vector ``dynamic_slice`` and no 1-D-to-2-D shape cast of masks happen in
+the kernel. ``check_vmem_budget`` bounds the resident working set (filter
+and delta operands) at 8 MiB and ``VMEM_LIMIT_BYTES`` gives the compiler
+the room for the in/out copies and temporaries. Off-TPU the kernel runs in
+interpret mode and is checked bit-exactly against the jnp step in
+tests/test_sketch_template.py; tests/test_tpu_compile.py compiles it for a
+v5e.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..core.batched import (BatchRandomness, BatchResult, intra_batch_seen,
-                            ring_push, sbf_planes_3d)
-from ..core.hashing import derive_seeds, hash_positions
-from ..core.packed import (clamped_run_counts, planes_saturating_add,
-                           planes_saturating_sub, planes_set_value, split_pos)
-from ..core.state import FilterState
-from .common import (DEFAULT_CHUNK_B, DEFAULT_TILE_W, check_vmem_budget,
-                     chunk_or, counter_vmem_words, largest_tile,
-                     popcount_sum)
+from ..core.batched import make_bitset_step, make_counter_planes_step
+from ..core.packed import (clamped_run_counts, delta_from_sorted_positions,
+                           planes_nonzero, planes_saturating_add,
+                           planes_saturating_sub, planes_set_value)
+from .common import (DEFAULT_CHUNK_B, DEFAULT_TILE_W, VMEM_LIMIT_BYTES,
+                     check_vmem_budget, chunk_or, counter_vmem_words,
+                     event_chunk, largest_tile, popcount_sum)
 
 
 def make_fused_step(cfg, spec=None, *, tile_w: int = DEFAULT_TILE_W,
                     chunk_b: int = DEFAULT_CHUNK_B,
                     interpret: bool | None = None,
                     params_aware: bool = False):
-    """BatchedStep for ``cfg.backend == "pallas"`` — generated from the
-    variant's ``SketchSpec`` (or an explicit ``spec``), same signature and
-    bit-identical results as the jnp step from the same spec. ``chunk_b``
-    applies to the bitset family only (the counter kernels consume
-    pre-reduced word deltas, not per-element scatters).
+    """BatchedStep for ``cfg.backend == "pallas"`` — the jnp step of the
+    variant's ``SketchSpec`` (or an explicit ``spec``) with the filter
+    update in one Pallas kernel; same signature, bit-identical results.
+    ``interpret=None`` runs the kernel compiled on a TPU and interpreted
+    elsewhere. ``chunk_b`` bounds the event chunk of the counter
+    family's accumulate form.
 
     ``params_aware=True`` is the fleet form (DESIGN §4.6): the step takes a
-    trailing ``TenantStepParams`` whose traced scalars ride into the kernel
-    as two extra (1,)-operands — the cms/hh verdict threshold and the sbf
-    set-to-Max ceiling — replacing the static config values at exactly the
-    seams the jnp twin replaces them (``core.batched``), so the two
-    backends stay bit-identical per tenant under ``jax.vmap``. The swbf
-    window modulus stays outside the kernel with the ring push."""
+    trailing ``TenantStepParams``. Its verdict threshold acts in the jnp
+    decide; its sbf set-to-Max ceiling rides into the kernel as an SMEM
+    scalar. Bitset decisions have no value-like knob, so that family
+    accepts and ignores the params."""
     cfg = cfg.validate()
     if spec is None:
         from ..core.sketch import get_spec
         spec = get_spec(cfg.variant)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not cfg.is_planes:
+        raise ValueError(
+            f"the fused {cfg.variant} kernel needs the bit-plane layout "
+            f"(cfg.layout='planes'); got {cfg.effective_layout!r}")
+    kw = dict(tile_w=tile_w, chunk_b=chunk_b, interpret=interpret)
     if spec.family == "counter":
-        if not cfg.is_planes:
-            raise ValueError(
-                f"the fused {cfg.variant} kernel needs the bit-plane layout "
-                f"(cfg.layout='planes'); got {cfg.effective_layout!r}")
-        return _make_counter_kernel_step(cfg, spec, tile_w=tile_w,
-                                         chunk_b=chunk_b, interpret=interpret,
-                                         params_aware=params_aware)
-    step = _make_bitset_kernel_step(cfg, spec, tile_w=tile_w,
-                                    chunk_b=chunk_b, interpret=interpret)
+        return make_counter_planes_step(
+            cfg, spec, params_aware=params_aware,
+            apply=_counter_apply(cfg, spec, **kw))
+    step = make_bitset_step(cfg, spec, apply=_bitset_apply(cfg, **kw))
     if not params_aware:
         return step
-    # bitset decisions have no value-like config knob — accept and ignore
-    # the params so the vmapped fleet signature stays uniform (§4.6)
     return lambda state, keys, valid, tp: step(state, keys, valid)
 
 
-# ---------------- counter family (d-bit plane cells) --------------------- //
+# ------------------------------------------------------------ the kernel //
+
+def _sweep(rows, dense, events, scalars, combine, n_loads, *, tile_w,
+           interpret, chunk):
+    """One pallas_call over the (R, W) uint32 ``rows``, updated in place.
+
+    ``dense``: (n, W) uint32 delta arrays read tile by tile beside the
+    rows. ``events``: (w_idx (1, E) int32, masks (n_m, E) uint32) pairs,
+    OR-accumulated per tile into n_m word rows that share the one index
+    row, E a multiple of ``chunk``. ``scalars``: () int32 values, read
+    from SMEM. ``combine(a, dense_tiles, event_tiles, scalars)`` maps the
+    tile's word rows to (new word rows, n_loads int32 load deltas).
+    Returns (new rows, (n_loads,) int32 summed load deltas)."""
+    n_rows, w = rows.shape
+    tw = largest_tile(w, tile_w)
+    n_tiles = w // tw
+
+    def accumulate(w_ref, m_ref, base):
+        lane = base + jax.lax.iota(jnp.int32, tw)
+        n_m = m_ref.shape[0]
+
+        def body(c, accs):
+            off = pl.multiple_of(c * chunk, chunk)
+            w_idx = w_ref[0, pl.ds(off, chunk)]
+            return tuple(
+                accs[i] | chunk_or(w_idx, m_ref[i, pl.ds(off, chunk)], lane)
+                for i in range(n_m))
+
+        zero = tuple(jnp.zeros((tw,), jnp.uint32) for _ in range(n_m))
+        return list(jax.lax.fori_loop(0, m_ref.shape[1] // chunk, body,
+                                      zero))
+
+    def kernel(*refs):
+        it = iter(refs)
+        rows_ref = next(it)
+        dense_refs = [next(it) for _ in dense]
+        event_refs = [(next(it), next(it)) for _ in events]
+        sc = [next(it)[0] for _ in scalars]
+        out_ref, dload_ref = next(it), next(it)
+
+        def tile_body(t, dl):
+            base = pl.multiple_of(t * tw, tw)
+            a = [rows_ref[i, pl.ds(base, tw)] for i in range(n_rows)]
+            dt = [[r[i, pl.ds(base, tw)] for i in range(r.shape[0])]
+                  for r in dense_refs]
+            et = [accumulate(wr, mr, base) for wr, mr in event_refs]
+            new, dls = combine(a, dt, et, sc)
+            for i in range(n_rows):
+                out_ref[i, pl.ds(base, tw)] = new[i]
+            return tuple(x + y for x, y in zip(dl, dls))
+
+        dl = jax.lax.fori_loop(0, n_tiles, tile_body,
+                               (jnp.int32(0),) * n_loads)
+        for i in range(n_loads):
+            dload_ref[i] = dl[i]
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    operands = [rows, *dense, *(x for ev in events for x in ev),
+                *(jnp.reshape(s, (1,)).astype(jnp.int32) for s in scalars)]
+    new_rows, dload = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((n_rows, w), jnp.uint32),
+                   jax.ShapeDtypeStruct((n_loads,), jnp.int32)],
+        in_specs=[vmem] * (len(operands) - len(scalars))
+        + [smem] * len(scalars),
+        out_specs=[vmem, smem],
+        input_output_aliases={0: 0},          # filter updated in place
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="fused_filter_sweep",
+    )(*operands)
+    return new_rows, dload
+
 
 def _event_operands(events, heads, cmax, rows, w, chunk):
-    """Sorted event cells -> the accumulate mode's kernel operands (§3.9):
-    per-event word index plus head-gated contribution mask rows — the exact
-    words the outside ``count_planes_from_sorted`` / set-OR scatter would
-    have built, one row per count bit-plane (``rows`` == 1 with cmax == 0
+    """Sorted event cells -> the accumulate form's kernel operands (§3.9):
+    the (1, E) word index and the (rows, E) head-gated contribution masks —
+    the exact words the outside ``count_planes_from_sorted`` / set-OR
+    scatter would have built, one row per count bit-plane (``cmax`` == 0
     selects the single-bit set-to-Max form). Sentinel events (32·W) land on
-    word index W, which matches no tile lane — the in-kernel OR drops them
-    exactly like the scatter's mode='drop'. Padded to a multiple of
-    ``chunk`` (the tree-OR needs power-of-two chunks)."""
+    word index W, which matches no tile lane. Padded to a multiple of
+    ``chunk``."""
     w_idx = (events >> 5).astype(jnp.int32)
     bit = (events & 31).astype(jnp.uint32)
     if cmax == 0:
@@ -122,342 +186,87 @@ def _event_operands(events, heads, cmax, rows, w, chunk):
         masks = jnp.stack([((cnt >> p) & jnp.uint32(1)) << bit
                            for p in range(rows)])
     pad = (-events.shape[0]) % chunk
-    if pad:
-        w_idx = jnp.pad(w_idx, (0, pad), constant_values=w)
-        masks = jnp.pad(masks, ((0, 0), (0, pad)))
-    return w_idx, masks
+    w_idx = jnp.pad(w_idx, (0, pad), constant_values=w)[None]
+    return w_idx, jnp.pad(masks, ((0, 0), (0, pad)))
 
 
-def _make_counter_kernel_step(cfg, spec, *, tile_w: int, chunk_b: int,
-                              interpret: bool | None,
-                              params_aware: bool = False):
-    s, w = cfg.s, cfg.s_words
-    d, k = cfg.n_planes, cfg.k
-    # set-to-Max writes the sketch's counter ceiling (sbf_max), which may sit
-    # below the plane capacity 2^d - 1
-    cmax = cfg.sbf_max
-    seeds = derive_seeds(cfg.seed, cfg.k, channel=0)
-    bseeds = (derive_seeds(cfg.seed, cfg.k, channel=1)
-              if cfg.block_bits else None)
-    squeeze = d == 1
-    decide = spec.make_decide(cfg)
-    events_fn = spec.make_events(cfg)
+# ------------------------------------------------- bitset family (k, W) //
+
+def _bitset_apply(cfg, *, tile_w, chunk_b, interpret):
+    k, w = cfg.k, cfg.s_words
+
+    def combine(a, dt, et, sc):
+        ins, dele = dt
+        new, dls = [], []
+        for f in range(k):
+            i_, d_ = ins[f], dele[f]
+            new.append((a[f] & ~d_) | i_)
+            dls.append(popcount_sum(i_ & ~a[f])
+                       - popcount_sum(a[f] & d_ & ~i_))
+        return new, tuple(dls)
+
+    def apply(bits, load, spi, spd):
+        dense = [delta_from_sorted_positions(spi, w),
+                 delta_from_sorted_positions(spd, w)]
+        check_vmem_budget(bits.nbytes + sum(x.nbytes for x in dense),
+                          "packed filter + delta rows")
+        new, dload = _sweep(bits, dense, [], [], combine, k,
+                            tile_w=tile_w, interpret=interpret, chunk=chunk_b)
+        return new, load + dload
+
+    return apply
+
+
+# --------------------------------------------- counter family (d, W) //
+
+def _counter_apply(cfg, spec, *, tile_w, chunk_b, interpret):
+    d, w = cfg.n_planes, cfg.s_words
     has_sub, set_mode = spec.has_sub, spec.combine == "set"
-    uses_seen, value_probe = spec.uses_seen, spec.probe == "value"
     accumulate = cfg.kernel_accumulate
-    # VMEM working set: the planes, the subtract planes if the sketch decays,
-    # and the insert operand — one OR word row for set-to-Max, d count planes
-    # for saturating add (sbf: (2d+1)·W·4, swbf: 3d·W·4, cms/hh: 2d·W·4).
-    # Accumulate mode (§3.9) swaps the delta planes for per-event operands,
-    # sized by the event counts at call time. The row count is shared with
-    # the static lint-rule mirror (common.fused_resident_bytes, DESIGN §6).
-    vmem_words = counter_vmem_words(d, has_sub=has_sub, set_mode=set_mode,
-                                    accumulate=accumulate)
     # saturating subtract/add clamp counts to the plane capacity; set-to-Max
     # events are single OR bits (cmax == 0 selects that form)
-    sub_cmax = cmax if set_mode else (1 << d) - 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    sub_cmax = cfg.sbf_max if set_mode else (1 << d) - 1
+    vmem_words = counter_vmem_words(d, has_sub=has_sub, set_mode=set_mode,
+                                    accumulate=accumulate)
 
-    thresholded = spec.thresholded
-
-    def step(state: FilterState, keys: jnp.ndarray, valid: jnp.ndarray,
-             tp=None):
-        b = keys.shape[0]
-        planes = sbf_planes_3d(state.bits)                       # (d, 1, W)
-        tw = largest_tile(w, tile_w)
-        n_tiles = w // tw
-
-        pos = hash_positions(keys, seeds, s, cfg.block_bits, bseeds)  # (B, k)
-        iw, im = split_pos(pos)
-        seen = intra_batch_seen(keys, valid) if uses_seen else None
-        if spec.draw is not None:
-            rng, rnd = spec.draw(cfg, state.rng, b)
-        else:
-            rng, rnd = state.rng, None
-        ev = events_fn(state, pos, valid, rnd)
-
-        operands = [planes]
+    def apply(planes, load, ev, cmax):
+        static_cmax = isinstance(cmax, (int, np.integer))
+        chunk = event_chunk(ev.ins_events.shape[0], chunk_b)
         if accumulate:
-            # per-event operands; the (d, W) plane scatters the events_fn
-            # also built are never consumed and fold away under DCE (§3.9)
-            tbc = 1 << max(3, min(chunk_b, ev.ins_events.shape[0]) - 1
-                           ).bit_length()
+            events = []
             if has_sub:
-                sub_w_op, sub_m_op = _event_operands(
-                    ev.sub_events, ev.sub_heads, sub_cmax, d, w, tbc)
-                operands += [sub_w_op, sub_m_op]
-            ins_w_op, ins_m_op = _event_operands(
+                events.append(_event_operands(ev.sub_events, ev.sub_heads,
+                                              sub_cmax, d, w, chunk))
+            events.append(_event_operands(
                 ev.ins_events, ev.ins_heads, 0 if set_mode else (1 << d) - 1,
-                d, w, tbc)
-            operands += [ins_w_op, ins_m_op]
-            ev_words = (sum(x.size for x in (sub_w_op, sub_m_op))
-                        if has_sub else 0) + ins_w_op.size + ins_m_op.size
+                d, w, chunk))
+            dense = []
         else:
+            events = []
+            dense = [ev.sub_planes] if has_sub else []
+            dense.append(ev.set_delta[None] if set_mode else ev.add_planes)
+        check_vmem_budget(
+            vmem_words * w * 4
+            + sum(x.nbytes for e in events for x in e),
+            f"{cfg.variant} planes + event "
+            f"{'operands' if accumulate else 'deltas'}")
+        scalars = [] if (static_cmax or not set_mode) else [cmax]
+
+        def combine(a, dt, et, sc):
+            ops = iter(et if accumulate else dt)
+            r = a
             if has_sub:
-                operands.append(ev.sub_planes)
-            operands.append(ev.set_delta if set_mode else ev.add_planes)
-            ev_words = 0
-        check_vmem_budget(vmem_words * w * 4 + ev_words * 4,
-                          f"{cfg.variant} planes + event "
-                          f"{'operands' if accumulate else 'deltas'}")
-        operands += [iw, im, valid.astype(jnp.int32)]
-        if uses_seen:
-            operands.append(seen.astype(jnp.int32))
-        operands.append(state.load)
-        if params_aware and thresholded:
-            operands.append(jnp.reshape(tp.threshold, (1,)).astype(jnp.int32))
-        if params_aware and set_mode:
-            operands.append(jnp.reshape(tp.max_value, (1,)).astype(jnp.int32))
-
-        def kernel(*refs):
-            it = iter(refs)
-            planes_ref = next(it)
-            if accumulate:
-                sub_w_ref, sub_m_ref = ((next(it), next(it))
-                                        if has_sub else (None, None))
-                ins_w_ref, ins_m_ref = next(it), next(it)
-                sub_ref = ins_ref = None
+                r = planes_saturating_sub(r, next(ops))
+            if set_mode:
+                r = planes_set_value(r, next(ops)[0],
+                                     cmax if static_cmax else sc[0])
             else:
-                sub_ref = next(it) if has_sub else None
-                ins_ref = next(it)
-                sub_w_ref = sub_m_ref = ins_w_ref = ins_m_ref = None
-            iw_ref, im_ref, valid_ref = next(it), next(it), next(it)
-            seen_ref = next(it) if uses_seen else None
-            load_ref = next(it)
-            thr_ref = next(it) if (params_aware and thresholded) else None
-            cmax_ref = next(it) if (params_aware and set_mode) else None
-            out_ref, dup_ref, load_out_ref = next(it), next(it), next(it)
+                r = planes_saturating_add(r, next(ops))
+            return r, (popcount_sum(planes_nonzero(r))
+                       - popcount_sum(planes_nonzero(a)),)
 
-            iw_ = iw_ref[...]
-            im_ = im_ref[...]
-            valid_ = valid_ref[...] != 0
-            rows = [planes_ref[p, 0, :] for p in range(d)]
-            # --- probe, in the SAME dtype the jnp step feeds its decide --- //
-            cols = []
-            for f in range(k):
-                if value_probe:
-                    # d-bit cell value: per-plane bit test, shift-OR
-                    v = jnp.zeros((iw_.shape[0],), jnp.int32)
-                    for p in range(d):
-                        bit = (rows[p][iw_[:, f]] & im_[:, f]) != 0
-                        v = v | (bit.astype(jnp.int32) << p)
-                    cols.append(v)
-                else:
-                    # nonzero test: OR of every plane's gathered word
-                    got = rows[0][iw_[:, f]]
-                    for p in range(1, d):
-                        got = got | rows[p][iw_[:, f]]
-                    cols.append((got & im_[:, f]) != 0)
-            vals = jnp.stack(cols, axis=1)
-            # --- decide: shared spec logic (bit-identical to jnp path) ---- //
-            seen_ = (seen_ref[...] != 0) if uses_seen else None
-            if thr_ref is not None:
-                dup = decide(vals, valid_, seen_, t=thr_ref[0])
-            else:
-                dup = decide(vals, valid_, seen_)
-            dup_ref[...] = dup.astype(jnp.int32)
+        new, dload = _sweep(planes, dense, events, scalars, combine, 1,
+                            tile_w=tile_w, interpret=interpret, chunk=chunk)
+        return new, load + dload
 
-            if accumulate:
-                sub_w_ = sub_w_ref[...] if has_sub else None
-                sub_m_ = sub_m_ref[...] if has_sub else None
-                ins_w_, ins_m_ = ins_w_ref[...], ins_m_ref[...]
-
-            def accum_tile(w_idx, m_rows, n_rows, lane):
-                # per-event OR-accumulation into the tile (§3.9): the same
-                # chunked tree-OR the bitset kernel uses — heads are unique
-                # per cell, so bits never collide within a plane row
-                out = []
-                for p in range(n_rows):
-                    acc = jnp.zeros(lane.shape, jnp.uint32)
-                    for c in range(w_idx.shape[0] // tbc):
-                        sl = slice(c * tbc, (c + 1) * tbc)
-                        acc = acc | chunk_or(w_idx[sl], m_rows[p][sl], lane)
-                    out.append(acc)
-                return out
-
-            # --- fused subtract + set/add + load sweep -------------------- //
-            def tile_body(t, dload):
-                base = t * tw
-                lane = base + jax.lax.iota(jnp.int32, tw)
-                a = jnp.stack([jax.lax.dynamic_slice(rows[p], (base,), (tw,))
-                               for p in range(d)])
-                r = a
-                if has_sub:
-                    if accumulate:
-                        e = jnp.stack(accum_tile(sub_w_, sub_m_, d, lane))
-                    else:
-                        e = jnp.stack([
-                            jax.lax.dynamic_slice(sub_ref[p, :], (base,),
-                                                  (tw,))
-                            for p in range(d)])
-                    r = planes_saturating_sub(r, e)
-                if set_mode:
-                    if accumulate:
-                        (i,) = accum_tile(ins_w_, ins_m_, 1, lane)
-                    else:
-                        i = jax.lax.dynamic_slice(ins_ref[...], (base,),
-                                                  (tw,))
-                    cm = cmax_ref[0] if cmax_ref is not None else cmax
-                    r = planes_set_value(r, i, cm)
-                else:
-                    if accumulate:
-                        c = jnp.stack(accum_tile(ins_w_, ins_m_, d, lane))
-                    else:
-                        c = jnp.stack([
-                            jax.lax.dynamic_slice(ins_ref[p, :], (base,),
-                                                  (tw,))
-                            for p in range(d)])
-                    r = planes_saturating_add(r, c)
-                pre_nz, post_nz = a[0], r[0]
-                for p in range(d):
-                    out_ref[p, 0, pl.ds(base, tw)] = r[p]
-                    if p:
-                        pre_nz = pre_nz | a[p]
-                        post_nz = post_nz | r[p]
-                return dload + popcount_sum(post_nz) - popcount_sum(pre_nz)
-
-            dload = jax.lax.fori_loop(0, n_tiles, tile_body, jnp.int32(0))
-            load_out_ref[0] = load_ref[0] + dload
-
-        new_planes, dup_i, new_load = pl.pallas_call(
-            kernel,
-            out_shape=[
-                jax.ShapeDtypeStruct((d, 1, w), jnp.uint32),
-                jax.ShapeDtypeStruct((b,), jnp.int32),
-                jax.ShapeDtypeStruct((1,), jnp.int32),
-            ],
-            input_output_aliases={0: 0},     # planes updated in place
-            interpret=interpret,
-        )(*operands)
-
-        bits = new_planes[0] if squeeze else new_planes
-        ring = state.ring
-        if ev.ring_payload is not None:
-            # the ring is engine state, not kernel state — jnp on purpose
-            window = tp.window if params_aware else cfg.window
-            ring = ring_push(ring, ev.ring_payload, window)
-        n_valid = valid.sum(dtype=jnp.int32)
-        new = FilterState(bits, state.position + n_valid, new_load, rng, ring)
-        return new, BatchResult(dup=dup_i != 0, inserted=valid)
-
-    return step
-
-
-# ---------------- bitset family (packed 1-bit rows) ---------------------- //
-
-def _make_bitset_kernel_step(cfg, spec, *, tile_w: int, chunk_b: int,
-                             interpret: bool | None):
-    chunk_b = 1 << max(3, chunk_b - 1).bit_length()   # tree-OR needs pow2
-    s, k = cfg.s, cfg.k
-    seeds = derive_seeds(cfg.seed, cfg.k, channel=0)
-    bseeds = (derive_seeds(cfg.seed, cfg.k, channel=1)
-              if cfg.block_bits else None)
-    decide = spec.make_decide(cfg)
-    draw = spec.draw
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    def step(state: FilterState, keys: jnp.ndarray, valid: jnp.ndarray):
-        b = keys.shape[0]
-        words = state.bits
-        k_, w = words.shape
-        check_vmem_budget(k_ * w * 4, "packed filter")
-        tw = largest_tile(w, tile_w)
-        n_tiles = w // tw
-
-        pos = hash_positions(keys, seeds, s, cfg.block_bits, bseeds)  # (B, k)
-        iw, im = split_pos(pos)
-        seen = intra_batch_seen(keys, valid)
-        i_t = state.position + jnp.arange(b, dtype=jnp.int32)
-        rng, rnd = draw(cfg, state.rng, b)
-        dw, dm = split_pos(rnd.del_pos)
-
-        # pad the batch to a power-of-two chunk multiple; padded lanes carry
-        # sentinel word index W (matches no lane) and valid=0
-        tbc = chunk_b if b >= chunk_b else max(8, 1 << (b - 1).bit_length())
-        bp = -(-b // tbc) * tbc
-        padb = bp - b
-
-        def pad1(x, v):
-            return jnp.pad(x, (0, padb), constant_values=v)
-
-        def pad2(x, v):
-            return jnp.pad(x, ((0, padb), (0, 0)), constant_values=v)
-
-        iw_p, im_p = pad2(iw, w), pad2(im, 0)
-        dw_p, dm_p = pad2(dw, w), pad2(dm, 0)
-        valid_p = pad1(valid.astype(jnp.int32), 0)
-        seen_p = pad1(seen.astype(jnp.int32), 0)
-        it_p = pad1(i_t, 1)
-        ub_p = pad1(rnd.u_bern, 0)
-        ua_p = pad2(rnd.u_aux, 0)
-        wh_p = pad1(rnd.which, 0)
-
-        def kernel(words_ref, iw_ref, im_ref, dw_ref, dm_ref, valid_ref,
-                   seen_ref, ub_ref, ua_ref, wh_ref, it_ref, load_ref,
-                   out_words_ref, dup_ref, ins_ref, load_out_ref):
-            iw_ = iw_ref[...]
-            im_ = im_ref[...]
-            dw_ = dw_ref[...]
-            dm_ = dm_ref[...]
-            valid_ = valid_ref[...] != 0
-            seen_ = seen_ref[...] != 0
-            load_ = load_ref[...]
-            # --- probe: every row's pre-update words, gathered in VMEM ---- //
-            rows = [words_ref[f, :] for f in range(k)]
-            vals = jnp.stack(
-                [((rows[f][iw_[:, f]] & im_[:, f]) != 0).astype(jnp.uint8)
-                 for f in range(k)], axis=1)
-            # --- decide: shared spec logic (bit-identical to jnp path) ---- //
-            krnd = BatchRandomness(del_pos=dw_, u_bern=ub_ref[...],
-                                   u_aux=ua_ref[...], which=wh_ref[...])
-            dup, insert, del_mask = decide(vals, valid_, seen_, it_ref[...],
-                                           load_, krnd)
-            dup_ref[...] = dup.astype(jnp.int32)
-            ins_ref[...] = insert.astype(jnp.int32)
-            # --- fused ANDNOT + OR sweep, one pass over the filter -------- //
-            for f in range(k):
-                iwf = jnp.where(insert, iw_[:, f], w)
-                dwf = jnp.where(del_mask[:, f], dw_[:, f], w)
-                imf, dmf = im_[:, f], dm_[:, f]
-                row = rows[f]
-
-                def tile_body(t, dload, f=f, iwf=iwf, dwf=dwf, imf=imf,
-                              dmf=dmf, row=row):
-                    base = t * tw
-                    lane = base + jax.lax.iota(jnp.int32, tw)
-                    a = jax.lax.dynamic_slice(row, (base,), (tw,))
-                    delta_i = jnp.zeros((tw,), jnp.uint32)
-                    delta_d = jnp.zeros((tw,), jnp.uint32)
-                    for c in range(bp // tbc):
-                        sl = slice(c * tbc, (c + 1) * tbc)
-                        delta_i = delta_i | chunk_or(iwf[sl], imf[sl], lane)
-                        delta_d = delta_d | chunk_or(dwf[sl], dmf[sl], lane)
-                    out_words_ref[f, pl.ds(base, tw)] = (a & ~delta_d) | delta_i
-                    # exact load delta, from words already in registers
-                    gained = popcount_sum(delta_i & ~a)
-                    lost = popcount_sum(a & delta_d & ~delta_i)
-                    return dload + gained - lost
-
-                dload = jax.lax.fori_loop(0, n_tiles, tile_body, jnp.int32(0))
-                load_out_ref[f] = load_[f] + dload
-
-        new_words, dup_i, ins_i, new_load = pl.pallas_call(
-            kernel,
-            out_shape=[
-                jax.ShapeDtypeStruct((k, w), jnp.uint32),
-                jax.ShapeDtypeStruct((bp,), jnp.int32),
-                jax.ShapeDtypeStruct((bp,), jnp.int32),
-                jax.ShapeDtypeStruct((k,), jnp.int32),
-            ],
-            input_output_aliases={0: 0},     # filter updated in place
-            interpret=interpret,
-        )(words, iw_p, im_p, dw_p, dm_p, valid_p, seen_p, ub_p, ua_p, wh_p,
-          it_p, state.load)
-
-        n_valid = valid.sum(dtype=jnp.int32)
-        new = FilterState(new_words, state.position + n_valid, new_load, rng)
-        return new, BatchResult(dup=dup_i[:b] != 0, inserted=ins_i[:b] != 0)
-
-    return step
+    return apply

@@ -37,7 +37,7 @@ import jax           # noqa: E402
 import numpy as np   # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh                       # noqa: E402
+from repro.compat import make_mesh, set_mesh                       # noqa: E402
 from repro.configs import get_arch                      # noqa: E402
 from repro.configs.registry import LMArch               # noqa: E402
 from repro.launch.analysis import analyze_compiled      # noqa: E402
@@ -242,6 +242,7 @@ def _overlap_env(flags):
     env["XLA_FLAGS"] = " ".join(
         (f"--xla_force_host_platform_device_count={OVERLAP_DEVICES}",)
         + tuple(flags))
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices; never the chip
     env["PYTHONPATH"] = src + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env
@@ -271,12 +272,12 @@ def overlap_worker(n: int = 1 << 17) -> None:
     pipelined swbf ingest at 8 simulated devices, best-of-3 wall-clock."""
     import jax.numpy as jnp
 
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.core import DedupConfig
     from repro.dedup import ShardedDedup, ShardedDedupConfig
 
     assert len(jax.devices()) == OVERLAP_DEVICES, jax.devices()
-    mesh = jax.make_mesh((OVERLAP_DEVICES, 1), ("data", "model"))
+    mesh = make_mesh((OVERLAP_DEVICES, 1), ("data", "model"))
     cfg = DedupConfig.for_variant("swbf", window=8, memory_bits=1 << 20,
                                   batch_size=16384, packed=True)
     sd = ShardedDedup(ShardedDedupConfig(base=cfg, pipeline=True), mesh)

@@ -8,6 +8,7 @@ XLA_FLAGS before the first jax call, and tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from ..compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,11 +16,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Whatever the current process has — used by examples/tests."""
     n = len(jax.devices())
     data = max(1, n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -356,15 +356,38 @@ def test_cli_list_names_every_rule():
 # ------------------------------------------------- vmem formula cross-check //
 
 
-def test_fused_resident_bytes_matches_kernel_formula():
+def test_fused_resident_bytes_matches_kernel_formula(monkeypatch):
     """The static budget mirror must agree with the trace-time guard's
-    arithmetic for both families (bitset k·W words; counter d-plane words
-    plus event operands under kernel accumulation)."""
+    arithmetic for both families (bitset: k filter rows plus 2k delta rows,
+    or per-row event operands under kernel accumulation; counter: d-plane
+    words plus delta planes or event operands)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import fused_template
     from repro.kernels.common import (VMEM_FILTER_BYTES_LIMIT,
                                       counter_vmem_words,
                                       fused_resident_bytes)
+    from repro.core.state import init_state
+    seen = []
+    monkeypatch.setattr(fused_template, "check_vmem_budget",
+                        lambda nbytes, what: seen.append(nbytes))
+    for variant in ("rlbsbf", "sbf", "cms"):
+        for acc in (False, True):
+            cfg = dataclasses.replace(
+                _canon_cfg(variant, "planes", backend="pallas"),
+                kernel_accumulate=acc)
+            step = fused_template.make_fused_step(cfg)
+            b = cfg.batch_size
+            seen.clear()
+            jax.eval_shape(step, init_state(cfg),
+                           jax.ShapeDtypeStruct((b,), jnp.uint32),
+                           jax.ShapeDtypeStruct((b,), jnp.bool_))
+            assert seen == [fused_resident_bytes(cfg)], (variant, acc)
     bit = _canon_cfg("rlbsbf", "planes", backend="pallas")
-    assert fused_resident_bytes(bit) == bit.k * bit.s_words * 4
+    assert fused_resident_bytes(bit) == 3 * bit.k * bit.s_words * 4
     cnt = _canon_cfg("sbf", "planes", backend="pallas")
     words = counter_vmem_words(cnt.n_planes, has_sub=True, set_mode=True,
                                accumulate=cnt.kernel_accumulate)

@@ -153,3 +153,48 @@ def test_state_checkpoint_roundtrip_mid_stream():
     both = np.concatenate([np.asarray(d1), np.asarray(d2)])
     assert np.array_equal(both, np.asarray(dup_full))
     assert int(st2.position) == int(full_st.position)
+
+
+# ------------------------------------------------ the paper's 512 MB filter //
+@pytest.mark.parametrize("variant", ("rsbf", "bsbf", "bsbfsd", "rlbsbf"))
+def test_paper_512mb_stream_traces(variant):
+    """The paper's largest filter (s = 2^31 bits per filter at k = 2) traces
+    through the batched stream scan. jax.eval_shape allocates nothing."""
+    from repro.configs.paper_dedup import paper_config
+    cfg = paper_config(variant, 512, layout="planes")
+    d = Dedup(cfg)
+    st = jax.eval_shape(d.init)
+    b = cfg.batch_size
+    new, dups = jax.eval_shape(
+        d._stream_impl, st, jax.ShapeDtypeStruct((2, b), jnp.uint32),
+        jax.ShapeDtypeStruct((2, b), jnp.bool_))
+    assert new.bits.shape == (cfg.k, cfg.s_words)
+    assert dups.shape == (2, b) and dups.dtype == jnp.bool_
+
+
+def test_uniform_positions_s_2_31_in_range_and_unchanged_below():
+    """s >= 2^31 draws in uint32; below that the int32 draw is untouched."""
+    from repro.core.hashing import uniform_positions
+    key = jax.random.PRNGKey(3)
+    big = np.asarray(uniform_positions(key, (4096,), 1 << 31))
+    assert big.dtype == np.int32 and big.min() >= 0
+    assert big.max() > (1 << 30)          # the upper half is reached
+    small = uniform_positions(key, (4096,), (1 << 31) - 1)
+    ref = jax.random.randint(key, (4096,), 0, (1 << 31) - 1, dtype=jnp.int32)
+    assert np.array_equal(np.asarray(small), np.asarray(ref))
+
+
+def test_counter_family_beyond_int32_cells_rejected():
+    """The counter family's event cells are int32 with a 32·W sentinel, so
+    one shard holds fewer than 2^31 cells; sharding lifts the limit, and a
+    sharded service's base config may exceed it."""
+    from repro.configs.paper_dedup import paper_config
+    cfg = paper_config("sbf", 512, layout="planes")
+    with pytest.raises(ValueError, match="32·W"):
+        Dedup(cfg)
+    with pytest.raises(ValueError, match="int32"):
+        Dedup(DedupConfig.for_variant("rlbsbf", memory_bits=1 << 33,
+                                      layout="planes"))
+    sharded = paper_config("sbf", 512, layout="planes", shards=4)
+    assert sharded.check_int32_positions().s == 1 << 29
+    assert paper_config("sbf", 256, layout="planes").s == 1 << 30

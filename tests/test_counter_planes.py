@@ -36,6 +36,7 @@ from repro.core.batched import sbf_planes_3d
 from repro.core.packed import (pack_cells, planes_nonzero,
                                planes_saturating_add, planes_saturating_sub,
                                planes_set_value, unpack_cells)
+from repro.compat import make_mesh
 
 SMALL = dict(memory_bits=1 << 12, batch_size=256)
 
@@ -153,7 +154,7 @@ def test_sharded_sbf_planes_parity_1x1():
     on a 1x1 mesh, with zero overflow and one compiled scan each."""
     keys = np.random.default_rng(1).integers(0, 2000, 768).astype(np.uint32)
     from repro.dedup import ShardedDedup, ShardedDedupConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     dups = {}
     for label, kw in (("dense8", {}), ("planes", dict(layout="planes")),
                       ("pallas", dict(layout="planes", backend="pallas"))):

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import all_arch_ids, get_arch
 from repro.distributed.collectives import dequantize_int8, quantize_int8
 from repro.launch.analysis import collective_bytes, collective_counts
+from repro.compat import make_mesh
 
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
@@ -38,10 +39,10 @@ def test_sharded_dedup_equals_single_filter():
     filter's FPR/FNR, overflows nothing, and compiles exactly once."""
     out = _run_subprocess("""
         import numpy as np, jax, jax.numpy as jnp, json
-        from repro.compat import set_mesh
+        from repro.compat import make_mesh, set_mesh
         from repro.core import DedupConfig, Dedup
         from repro.dedup import ShardedDedup, ShardedDedupConfig, truth_from_stream
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = DedupConfig.for_variant("rlbsbf", memory_bits=1 << 17,
                                       batch_size=4096)
         sd = ShardedDedup(ShardedDedupConfig(base=cfg), mesh)
@@ -80,10 +81,10 @@ def test_sharded_rsbf_positions_are_per_shard():
     positions equals the number of routed (non-overflow) keys."""
     out = _run_subprocess("""
         import numpy as np, jax, jax.numpy as jnp, json
-        from repro.compat import set_mesh
+        from repro.compat import make_mesh, set_mesh
         from repro.core import DedupConfig
         from repro.dedup import ShardedDedup, ShardedDedupConfig
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = DedupConfig.for_variant("rsbf", memory_bits=1 << 15)
         sd = ShardedDedup(ShardedDedupConfig(base=cfg), mesh)
         state = sd.init()
@@ -111,10 +112,10 @@ def test_sharded_rsbf_positions_are_per_shard():
 def test_compressed_psum_error_feedback():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np, json
-        from repro.compat import set_mesh, shard_map
+        from repro.compat import make_mesh, set_mesh, shard_map
         from repro.distributed.collectives import compressed_psum
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def f(g):
@@ -182,7 +183,7 @@ def test_param_specs_divisible_everywhere():
 
 def _sharded_one_by_one(cfg):
     from repro.dedup import ShardedDedup, ShardedDedupConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return ShardedDedup(ShardedDedupConfig(base=cfg), mesh)
 
 
@@ -258,7 +259,7 @@ def test_sharded_overflow_accumulates_into_metrics_devicewise():
     from repro.dedup import (ShardedDedup, ShardedDedupConfig, StreamMetrics,
                              truth_from_stream)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = DedupConfig.for_variant("rlbsbf", memory_bits=1 << 14,
                                   batch_size=256)
     sd = ShardedDedup(ShardedDedupConfig(base=cfg, capacity_factor=0.5), mesh)
@@ -284,11 +285,11 @@ def test_pipelined_stream_matches_serial_bitwise():
     out = _run_subprocess("""
         import hashlib, json
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
+        from repro.compat import make_mesh, set_mesh
         from repro.core import DedupConfig
         from repro.data.streams import zipf_range_stream
         from repro.dedup import ShardedDedup, ShardedDedupConfig
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        mesh = make_mesh((4, 1), ("data", "model"))
         keys, _ = zipf_range_stream(4096, universe=1 << 11, a=1.2, seed=7)
         jk = jnp.asarray(keys)
         def run(cfg, pipe, **kw):
@@ -333,11 +334,11 @@ def test_pipelined_stream_donates_filter_planes():
         import json
         import jax, jax.numpy as jnp
         from repro.analysis import aliased_param_indices, entry_param_types
-        from repro.compat import set_mesh
+        from repro.compat import make_mesh, set_mesh
         from repro.core import DedupConfig
         from repro.dedup import ShardedDedup, ShardedDedupConfig
         S = 4
-        mesh = jax.make_mesh((S, 1), ("data", "model"))
+        mesh = make_mesh((S, 1), ("data", "model"))
         cfg = DedupConfig.for_variant("swbf", window=3, memory_bits=1 << 16,
                                       batch_size=512, packed=True)
         sd = ShardedDedup(ShardedDedupConfig(base=cfg), mesh)

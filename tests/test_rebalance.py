@@ -39,6 +39,7 @@ from repro.core.hashing import range_bucket
 from repro.core.state import init_router
 from repro.data.streams import zipf_range_stream
 from repro.dedup import ShardedDedup, ShardedDedupConfig
+from repro.compat import make_mesh
 
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
@@ -53,7 +54,7 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ------------------------------------------------------------- unit bits //
@@ -213,13 +214,13 @@ def test_router_meta_is_json_stampable(tmp_path):
 _PARITY_WORKER = """
     import json, hashlib
     import numpy as np, jax, jax.numpy as jnp
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.core import DedupConfig
     from repro.dedup import ShardedDedup, ShardedDedupConfig
     from repro.data.streams import zipf_range_stream
 
     devices = len(jax.devices())
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys, _ = zipf_range_stream(1 << 14, universe=1 << 13, a=1.2, seed=7)
     out = {"devices": devices}
     for tag, thr in (("on", 1.25), ("off", 0.0)):
@@ -272,7 +273,7 @@ def test_rebalance_fires_reduces_skew_and_preserves_verdicts():
 _CKPT_WORKER = """
     import tempfile
     import numpy as np, jax, jax.numpy as jnp
-    from repro.compat import set_mesh
+    from repro.compat import make_mesh, set_mesh
     from repro.checkpoint import (CheckpointManager, layout_meta,
                                   router_meta)
     from repro.core import DedupConfig
@@ -280,7 +281,7 @@ _CKPT_WORKER = """
     from repro.data.streams import zipf_range_stream
 
     devices = len(jax.devices())
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     keys, _ = zipf_range_stream(6144, universe=1 << 12, a=1.2, seed=3)
     for backend in ("jnp", "pallas"):
         cfg = DedupConfig.for_variant(

@@ -18,7 +18,6 @@ The tentpole invariants of the spec-driven generators:
 """
 
 import asyncio
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +27,9 @@ import pytest
 from repro.core import (ALL_VARIANTS, COUNTING_VARIANTS, Dedup, DedupConfig,
                         SKETCHES, get_spec)
 from repro.core.batched import sbf_planes_3d
+from repro.core.digests import PINNED_DIGESTS, pinned_config, run_digest
 from repro.core.packed import unpack_cells
+from repro.compat import make_mesh
 
 SMALL = dict(memory_bits=1 << 12, batch_size=256)
 
@@ -95,6 +96,28 @@ def test_kernel_accumulate_parity(name):
         _assert_states_equal(s0, s1, (name, sname))
 
 
+@pytest.mark.parametrize("name", ("rsbf", "bsbf", "bsbfsd", "rlbsbf"))
+def test_kernel_accumulate_is_noop_for_bitset(name):
+    """The bitset family has one kernel form (XLA-built delta rows):
+    ``kernel_accumulate`` traces the same program and claims the same
+    VMEM working set."""
+    import dataclasses
+    from repro.kernels.common import fused_resident_bytes
+    from repro.kernels.fused_template import make_fused_step
+    cfg = _variant_cfg(name, backend="pallas")
+    acc = dataclasses.replace(cfg, kernel_accumulate=True)
+    d = Dedup(cfg)
+    keys = jnp.zeros((cfg.batch_size,), jnp.uint32)
+    valid = jnp.ones((cfg.batch_size,), bool)
+
+    def jaxpr(c):
+        return str(jax.make_jaxpr(make_fused_step(c, interpret=True))(
+            d.init(), keys, valid))
+
+    assert jaxpr(acc) == jaxpr(cfg)
+    assert fused_resident_bytes(acc) == fused_resident_bytes(cfg)
+
+
 # ------------------------------------------------------------- parity grid //
 @pytest.mark.parametrize("name", GRID)
 def test_template_jnp_pallas_parity_grid(name):
@@ -132,53 +155,16 @@ def test_template_single_steps_with_ragged_valid(name):
 
 
 # ---------------------------------------------------------- pinned digests //
-# sha256 over (per-batch dup + inserted reports, final bits/load/position/
-# rng-key-data/ring) at memory_bits=1<<14, batch=256, 1024 mixed keys with a
-# ragged final batch — captured from the HAND-WRITTEN per-variant steps
-# immediately before the template refactor. The template generators must
-# reproduce these forever (the determinism contract of DESIGN.md §2/§3.8).
-PINNED_DIGESTS = {
-    "bsbf": "4e3f72a324d1eb32",
-    "bsbfsd": "9936da3ee28dfb25",
-    "rlbsbf": "2fa66ecae9583e86",
-    "rsbf": "6371d978a8821296",
-    "sbf": "be5220c6e677d339",
-    "sbf_d1": "b5702a4fbe9dc5c0",
-    "swbf": "4580749bdb028080",
-}
-
-
-def _run_digest(cfg):
-    eng = Dedup(cfg)
-    state = eng.init()
-    keys = np.random.RandomState(7).randint(0, 400, size=1024) \
-        .astype(np.uint32)
-    b = cfg.batch_size
-    h = hashlib.sha256()
-    for i in range(0, len(keys), b):
-        kb = jnp.asarray(keys[i:i + b])
-        valid = np.ones((b,), bool)
-        if i + b >= len(keys):
-            valid[b // 2:] = False          # ragged final batch
-        state, res = eng.process(state, kb, jnp.asarray(valid))
-        h.update(np.asarray(res.dup).tobytes())
-        h.update(np.asarray(res.inserted).tobytes())
-    h.update(np.asarray(state.bits).tobytes())
-    h.update(np.asarray(state.load).tobytes())
-    h.update(np.asarray(state.position).tobytes())
-    h.update(np.asarray(jax.random.key_data(state.rng)).tobytes())
-    if state.ring is not None:
-        h.update(np.asarray(state.ring.events).tobytes())
-        h.update(np.asarray(state.ring.slot).tobytes())
-    return h.hexdigest()[:16]
-
-
+# The digests, their config and stream live in repro.core.digests, shared
+# with chip_smoke.py, which checks the same digests on the TPU.
 @pytest.mark.parametrize("name", sorted(PINNED_DIGESTS))
 def test_pre_template_digests_pinned(name):
-    """The templated steps reproduce the hand-written steps bit-for-bit
-    (jnp backend; the grid above extends the guarantee to pallas)."""
-    cfg = _variant_cfg(name, memory_bits=1 << 14)
-    assert _run_digest(cfg) == PINNED_DIGESTS[name], name
+    """The templated steps reproduce the pinned verdict/state stream
+    bit-for-bit (jnp backend; the grid above extends the guarantee to
+    pallas)."""
+    cfg = pinned_config(name)
+    assert cfg == _variant_cfg(name, memory_bits=1 << 14)
+    assert run_digest(cfg) == PINNED_DIGESTS[name], name
 
 
 # ------------------------------------------------------------ the registry //
@@ -321,7 +307,7 @@ def test_counting_sharded_parity_1x1(variant):
     keys = np.random.default_rng(1).integers(0, 500, 768).astype(np.uint32)
     ref_eng = Dedup(DedupConfig.for_variant(variant, **SMALL))
     _, ref = ref_eng.run_stream(ref_eng.init(), jnp.asarray(keys))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for kw in ({}, dict(backend="pallas")):
         cfg = DedupConfig.for_variant(variant, **SMALL, **kw)
         sd = ShardedDedup(ShardedDedupConfig(base=cfg), mesh)

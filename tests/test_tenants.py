@@ -359,7 +359,7 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
 _SHARDED_WORKER = """
 import json
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from repro.compat import make_mesh, set_mesh
 from repro.core.config import DedupConfig
 from repro.dedup.sharded import ShardedDedup, ShardedDedupConfig
 
@@ -367,7 +367,7 @@ T, N = 8, 512
 base = DedupConfig(variant="{variant}", memory_bits=1 << 15, k=4,
                    batch_size=64, n_tenants=T, rebalance_buckets=T, seed=11)
 devices = len(jax.devices())
-mesh = jax.make_mesh((devices, 1), ("data", "model"))
+mesh = make_mesh((devices, 1), ("data", "model"))
 # capacity_factor covers the worst tenant-concentration a batch can carry
 # (all 64 lanes on one tenant -> one shard): dispatch overflow would drop
 # lanes and break the cross-mesh bit-identity this worker asserts

@@ -35,6 +35,7 @@ from repro.core.hashing import derive_seeds, hash_positions
 from repro.core.packed import planes_nonzero, popcount, unpack_cells
 from repro.core.state import init_state
 from repro.dedup import windowed_truth_from_stream
+from repro.compat import make_mesh
 
 SMALL = dict(memory_bits=1 << 12, batch_size=256)
 
@@ -182,7 +183,7 @@ def test_sharded_swbf_parity_1x1():
     keys = np.random.default_rng(1).integers(0, 2000, 768).astype(np.uint32)
     ref_eng = Dedup(DedupConfig.for_variant("swbf", window=4, **SMALL))
     _, ref = ref_eng.run_stream(ref_eng.init(), jnp.asarray(keys))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for kw in ({}, dict(backend="pallas")):
         cfg = DedupConfig.for_variant("swbf", window=4, **SMALL, **kw)
         sd = ShardedDedup(ShardedDedupConfig(base=cfg), mesh)
