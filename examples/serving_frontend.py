@@ -70,6 +70,10 @@ print(f"  shed rate {st['shed_rate']:.3f}   cache hit rate "
       f"{st['cache_hit_rate']:.3f}   dup rate {st['dup_rate']:.3f}")
 print(f"  compiled engine traces: {st['process_cache']} "
       f"(<= one per bucket x donation flag — the §5.2 no-retrace contract)")
+print(f"  mean queue wait {st['queue_wait_ms']:.2f} ms; stage spans "
+      f"(mean / longest ms, DESIGN.md §5.2):")
+for stage, v in st["stages"].items():
+    print(f"    {stage:<13}{v['mean_ms']:8.3f} {v['max_ms']:9.3f}")
 
 # the pre-frontend story: one synchronous serve() call per request
 sess = ServeSession(cfg, score_fn, buckets=BUCKETS)

@@ -16,6 +16,10 @@ that true as the code grows:
   * ``no-deprecated-shim-import`` — ``kernels/fused_step.py`` and
     ``fused_counter_step.py`` are deprecation shims; new src code imports
     ``kernels.fused_template``.
+  * ``tracing-choke-point`` — profiler annotations (the program's spans)
+    are made only in ``repro/tracing.py`` (DESIGN §7), so every span lands
+    in one registry under one naming scheme and no second span system
+    grows.
   * ``no-python-branch-on-tracer`` — an ``if``/``while`` on a local
     assigned from a jnp/lax/random call inside a hot module is a trace
     error (or silent concretization) waiting to happen. Heuristic: names
@@ -60,6 +64,10 @@ COMPAT_EXEMPT = ("compat.py",)
 
 SHIM_MODULES = ("fused_step", "fused_counter_step")
 SHIM_EXEMPT = ("kernels/fused_step.py", "kernels/fused_counter_step.py")
+
+# the span-making surfaces of jax.profiler, under any import spelling
+TRACE_ATTRS = ("TraceAnnotation", "StepTraceAnnotation", "annotate_function")
+TRACING_EXEMPT = ("repro/tracing.py",)
 
 HOST_SYNC_ATTRS = ("block_until_ready", "device_get", "item")
 NUMPY_SYNC_ATTRS = ("asarray", "array")
@@ -149,6 +157,34 @@ _register(SourceRule(
     "version-sensitive JAX surfaces are only touched through "
     "repro/compat.py (DESIGN §4.3)",
     _check_compat))
+
+
+def _check_tracing(relpath: str, tree: ast.AST, text: str, hot: bool
+                   ) -> List[Finding]:
+    if relpath.replace(os.sep, "/").endswith(TRACING_EXEMPT):
+        return []
+    findings = []
+    for node in ast.walk(tree):
+        used = None
+        if isinstance(node, ast.ImportFrom) and node.module and \
+                node.module.endswith("profiler"):
+            used = next((a.name for a in node.names
+                         if a.name in TRACE_ATTRS), None)
+        elif isinstance(node, ast.Attribute) and node.attr in TRACE_ATTRS:
+            used = dotted_name(node) or node.attr
+        if used:
+            findings.append(Finding(
+                "tracing-choke-point", f"{relpath}::{used}",
+                f"line {node.lineno}: `{used}` — make spans with "
+                f"repro.tracing.span (DESIGN §7)"))
+    return findings
+
+
+_register(SourceRule(
+    "tracing-choke-point",
+    "profiler annotations are only made in repro/tracing.py, the one "
+    "span registry (DESIGN §7)",
+    _check_tracing))
 
 
 def _check_host_sync(relpath: str, tree: ast.AST, text: str, hot: bool

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compat import jit_cache_size
+from ..tracing import span
 from .batched import BatchResult, make_batched_step, make_estimate_fn
 from .config import DedupConfig
 from .packed import unpack_cells
@@ -190,16 +191,18 @@ class Dedup:
         with invalid lanes. Returns per-element duplicate reports.
 
         The input ``state`` is donated (updated in place) — use the returned
-        state afterwards, never the argument."""
-        b = self.cfg.batch_size
-        n = keys.shape[0]
-        n_pad = (-n) % b
-        keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
-        valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
-        kb = keys_p.reshape(-1, b)
-        vb = valid.reshape(-1, b)
-        state, dups = self._stream(state, kb, vb)
-        return state, dups.reshape(-1)[:n]
+        state afterwards, never the argument. The ``dedup.stream.enqueue``
+        span times the host's part: pad, reshape and the scan's dispatch."""
+        with span("dedup.stream.enqueue"):
+            b = self.cfg.batch_size
+            n = keys.shape[0]
+            n_pad = (-n) % b
+            keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
+            valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
+            kb = keys_p.reshape(-1, b)
+            vb = valid.reshape(-1, b)
+            state, dups = self._stream(state, kb, vb)
+            return state, dups.reshape(-1)[:n]
 
     def stream_cache_size(self) -> int:
         """Number of compiled specializations of the stream scan (one per

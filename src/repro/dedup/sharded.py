@@ -79,6 +79,7 @@ from ..core.sketch import get_spec
 from ..core.state import (FilterState, RouterState, WindowRing, init_router,
                           init_state)
 from ..distributed.sharding import rebalance_collect
+from ..tracing import span
 
 _INT32_MAX = np.iinfo(np.int32).max
 
@@ -902,20 +903,22 @@ class ShardedDedup:
         sync).
 
         The input ``state`` is donated — use the returned state afterwards,
-        never the argument (same contract as ``Dedup.run_stream``)."""
+        never the argument (same contract as ``Dedup.run_stream``, and the
+        same ``dedup.stream.enqueue`` span around the host's part)."""
         b = self.scfg.base.batch_size
         if b % self.n_shards:
             raise ValueError(
                 f"batch_size {b} must divide by n_shards {self.n_shards}")
-        n = keys.shape[0]
-        n_pad = (-n) % b
-        keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
-        valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
-        kb = keys_p.reshape(-1, b)
-        vb = valid.reshape(-1, b)
-        stream = self._make_stream(b // self.n_shards)
-        state, dups, ovfs = stream(state, kb, vb)
-        return state, dups.reshape(-1)[:n], ovfs
+        with span("dedup.stream.enqueue"):
+            n = keys.shape[0]
+            n_pad = (-n) % b
+            keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
+            valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
+            kb = keys_p.reshape(-1, b)
+            vb = valid.reshape(-1, b)
+            stream = self._make_stream(b // self.n_shards)
+            state, dups, ovfs = stream(state, kb, vb)
+            return state, dups.reshape(-1)[:n], ovfs
 
     def run_tenant_stream(self, state: FilterState, keys: jnp.ndarray,
                           tenant: jnp.ndarray

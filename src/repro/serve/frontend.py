@@ -37,6 +37,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import hashlib
+import time
 from collections import deque
 from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
@@ -44,6 +45,7 @@ import numpy as np
 
 from ..core.config import DedupConfig
 from ..core.engine import Dedup
+from ..tracing import add, snapshot, span
 from .cache import ResponseCache
 
 DEFAULT_BUCKETS = (64, 256, 1024)
@@ -187,26 +189,29 @@ class MicroBatchExecutor:
         lane instead — T logical filters, still ONE launch (§4.6)."""
         n = keys.shape[0]
         width = self.bucket_for(n)
-        if self.fleet is not None:
-            import jax.numpy as jnp
-            if tenants is None:
-                tenants = np.zeros((n,), np.int32)
-            kp = np.zeros((width,), np.uint32)
-            tp = np.zeros((width,), np.int32)
-            vp = np.zeros((width,), bool)
-            kp[:n], tp[:n], vp[:n] = keys, tenants, True
-            self.state, res = self.fleet.process(
-                self.state, jnp.asarray(kp), jnp.asarray(tp),
-                jnp.asarray(vp))
+        seq = self.n_batches
+        # dispatch ends once the step is enqueued; verdict_wait is the
+        # step, the device slices and the copy to the host
+        with span("dedup.serve.dispatch", batch=seq):
+            if self.fleet is not None:
+                import jax.numpy as jnp
+                if tenants is None:
+                    tenants = np.zeros((n,), np.int32)
+                kp = np.zeros((width,), np.uint32)
+                tp = np.zeros((width,), np.int32)
+                vp = np.zeros((width,), bool)
+                kp[:n], tp[:n], vp[:n] = keys, tenants, True
+                self.state, res = self.fleet.process(
+                    self.state, jnp.asarray(kp), jnp.asarray(tp),
+                    jnp.asarray(vp))
+            else:
+                self.state, res = self.engine.process_padded(
+                    self.state, keys, width=width, donate=True)
+        with span("dedup.serve.verdict_wait", batch=seq):
             dup = np.asarray(res.dup)[:n]
-            if self.schedule is not None:
-                self.schedule.append((width, keys.copy(), tenants.copy()))
-        else:
-            self.state, res = self.engine.process_padded(
-                self.state, keys, width=width, donate=True)
-            dup = np.asarray(res.dup)
-            if self.schedule is not None:
-                self.schedule.append((width, keys.copy()))
+        if self.schedule is not None:
+            self.schedule.append((width, keys.copy()) if self.fleet is None
+                                 else (width, keys.copy(), tenants.copy()))
         self._digest.update(np.int64(dup.size).tobytes())
         self._digest.update(np.packbits(dup).tobytes())
         self.n_batches += 1
@@ -312,7 +317,8 @@ class ServeFrontend:
         self.queue_limit = (max_live_batches * self._exec.buckets[-1]
                             if queue_limit is None else queue_limit)
         self.flush_timeout = flush_timeout
-        self._queue: Deque[Tuple[int, int, Optional[dict],
+        # (key, tenant, payload, submit time, future)
+        self._queue: Deque[Tuple[int, int, Optional[dict], float,
                                  asyncio.Future]] = deque()
         self._running = False
         self._in_flight = 0
@@ -359,7 +365,8 @@ class ServeFrontend:
             self.n_shed += 1
             return ServeResult(VERDICT_RETRY)
         fut = self._loop.create_future()
-        self._queue.append((int(key), int(tenant), payload, fut))
+        self._queue.append((int(key), int(tenant), payload,
+                            time.perf_counter(), fut))
         self._arrived.set()
         return await fut
 
@@ -367,39 +374,53 @@ class ServeFrontend:
     async def _drain(self) -> None:
         bmax = self._exec.buckets[-1]
         while True:
-            while not self._queue:
-                if not self._running:
-                    return
-                self._arrived.clear()
-                await self._arrived.wait()
+            if not self._queue:
+                with span("dedup.serve.idle"):      # arrival-bound
+                    while not self._queue:
+                        if not self._running:
+                            return
+                        self._arrived.clear()
+                        await self._arrived.wait()
+            # batches are taken one at a time, each after the previous
+            # one's step: the executor's count numbers this one
+            seq = self._exec.n_batches
             # flush window: while the device is BUSY, let the batch fill
             # toward the largest bucket (never holding a partial batch
             # longer than flush_timeout — the tail-latency bound). When
             # nothing is in flight the wait would be pure added latency,
             # so dispatch greedily with whatever has queued.
             if self._in_flight > 0:
-                deadline = self._loop.time() + self.flush_timeout
-                while self._running and len(self._queue) < bmax:
-                    remaining = deadline - self._loop.time()
-                    if remaining <= 0:
-                        break
-                    self._arrived.clear()
-                    try:
-                        await asyncio.wait_for(self._arrived.wait(),
-                                               remaining)
-                    except asyncio.TimeoutError:
-                        break
-            await self._live.acquire()      # admission: max_live_batches
+                with span("dedup.serve.flush", batch=seq):
+                    deadline = self._loop.time() + self.flush_timeout
+                    while self._running and len(self._queue) < bmax:
+                        remaining = deadline - self._loop.time()
+                        if remaining <= 0:
+                            break
+                        self._arrived.clear()
+                        try:
+                            await asyncio.wait_for(self._arrived.wait(),
+                                                   remaining)
+                        except asyncio.TimeoutError:
+                            break
+            with span("dedup.serve.admit", batch=seq):
+                await self._live.acquire()  # admission: max_live_batches
             self._in_flight += 1
-            take = min(len(self._queue), bmax)
-            items = [self._queue.popleft() for _ in range(take)]
-            keys = np.fromiter((it[0] for it in items), np.uint32, take)
-            tenants = np.fromiter((it[1] for it in items), np.int32, take)
+            with span("dedup.serve.take", batch=seq):
+                take = min(len(self._queue), bmax)
+                items = [self._queue.popleft() for _ in range(take)]
+                keys = np.fromiter((it[0] for it in items), np.uint32, take)
+                tenants = np.fromiter((it[1] for it in items), np.int32,
+                                      take)
+                wait = time.perf_counter() - np.fromiter(
+                    (it[3] for it in items), np.float64, take)
+                add("dedup.serve.queue_wait", float(wait.sum()), take,
+                    float(wait.max()))
             try:
                 # device path in a worker thread: the event loop keeps
                 # ingesting (and shedding) while the engine step runs
-                dup = await self._loop.run_in_executor(
-                    None, self._exec.dedup_chunk, keys, tenants)
+                with span("dedup.serve.step", batch=seq):
+                    dup = await self._loop.run_in_executor(
+                        None, self._exec.dedup_chunk, keys, tenants)
             except Exception as e:          # fail the batch, keep serving
                 for *_kt, fut in items:
                     if not fut.done():
@@ -408,40 +429,44 @@ class ServeFrontend:
                 self._live.release()
                 continue
             # post-processing overlaps the NEXT batch's ingest + dedup
-            t = self._loop.create_task(self._post(items, keys, tenants, dup))
+            t = self._loop.create_task(
+                self._post(seq, items, keys, tenants, dup))
             self._post_tasks.add(t)
             t.add_done_callback(self._post_tasks.discard)
 
-    async def _post(self, items, keys: np.ndarray, tenants: np.ndarray,
-                    dup: np.ndarray) -> None:
+    async def _post(self, seq: int, items, keys: np.ndarray,
+                    tenants: np.ndarray, dup: np.ndarray) -> None:
         try:
-            # cache identity is tenant-scoped on a fleet (§4.6): tenants
-            # never see each other's cached responses
-            ckeys = self._exec.cache_keys(keys, tenants)
-            payload = None
-            if any(it[2] is not None for it in items):
-                fields = items[0][2].keys()
-                payload = {f: np.asarray([it[2][f] for it in items])
-                           for f in fields}
-                payload["key"] = keys
-            hit, vals = self._exec.cache.lookup(ckeys)
-            need = np.flatnonzero(~hit)
-            if need.size:
-                batch = {"key": keys} if payload is None else payload
-                sub = {f: np.asarray(v)[need] for f, v in batch.items()}
-                scores = np.asarray(await self._loop.run_in_executor(
-                    None, self._exec.score_fn, sub))
-                for j, i in enumerate(need):
-                    vals[i] = scores[j]
-                self._exec.cache.admit(ckeys[need], list(scores))
-            self._exec.n_cached += int(hit.sum())
-            self._exec.n_scored += int(need.size)
-            for i, (*_kt, fut) in enumerate(items):
-                if not fut.done():
-                    fut.set_result(ServeResult(
-                        VERDICT_OK, value=vals[i], dup=bool(dup[i]),
-                        cached=bool(hit[i])))
-            self.n_completed += len(items)
+            with span("dedup.serve.post", batch=seq):
+                # cache identity is tenant-scoped on a fleet (§4.6):
+                # tenants never see each other's cached responses
+                ckeys = self._exec.cache_keys(keys, tenants)
+                payload = None
+                if any(it[2] is not None for it in items):
+                    fields = items[0][2].keys()
+                    payload = {f: np.asarray([it[2][f] for it in items])
+                               for f in fields}
+                    payload["key"] = keys
+                hit, vals = self._exec.cache.lookup(ckeys)
+                need = np.flatnonzero(~hit)
+                if need.size:
+                    batch = {"key": keys} if payload is None else payload
+                    sub = {f: np.asarray(v)[need] for f, v in batch.items()}
+                    with span("dedup.serve.score", batch=seq):
+                        scores = np.asarray(await self._loop.run_in_executor(
+                            None, self._exec.score_fn, sub))
+                    for j, i in enumerate(need):
+                        vals[i] = scores[j]
+                    self._exec.cache.admit(ckeys[need], list(scores))
+                self._exec.n_cached += int(hit.sum())
+                self._exec.n_scored += int(need.size)
+                with span("dedup.serve.resolve", batch=seq):
+                    for i, (*_kt, fut) in enumerate(items):
+                        if not fut.done():
+                            fut.set_result(ServeResult(
+                                VERDICT_OK, value=vals[i], dup=bool(dup[i]),
+                                cached=bool(hit[i])))
+                self.n_completed += len(items)
         except Exception as e:              # fail the batch, keep serving
             for *_kt, fut in items:
                 if not fut.done():
@@ -456,7 +481,17 @@ class ServeFrontend:
         return self._exec
 
     def stats(self) -> dict:
+        """Counters of this front-end, and under ``stages`` each
+        ``dedup.serve.*`` span's mean and longest in ms from the
+        process-wide registry (``repro.tracing``: every front-end and
+        executor of the process), ``queue_wait_ms`` the mean wait of a
+        request from ``submit`` until taken into a micro-batch."""
         ex = self._exec
+        stages = {name[len("dedup.serve."):]: {
+            "mean_ms": 1e3 * v["total_s"] / v["count"],
+            "max_ms": 1e3 * v["max_s"]}
+            for name, v in sorted(snapshot().items())
+            if name.startswith("dedup.serve.") and v["count"]}
         return {
             "submitted": self.n_submitted, "completed": self.n_completed,
             "shed": self.n_shed,
@@ -466,4 +501,6 @@ class ServeFrontend:
             "cache_hit_rate": ex.n_cached / max(1, ex.n_requests),
             "dup_rate": ex.n_dup / max(1, ex.n_requests),
             "process_cache": ex.process_cache_size(),
+            "stages": stages,
+            "queue_wait_ms": stages.get("queue_wait", {}).get("mean_ms"),
         }

@@ -206,6 +206,23 @@ def test_source_rule_shim_import(tmp_path):
     assert [f.rule for f in found] == ["no-deprecated-shim-import"]
 
 
+def test_source_rule_tracing_choke_point(tmp_path):
+    found = _lint_snippet(tmp_path, """\
+        import jax
+        from jax.profiler import TraceAnnotation
+        def f():
+            with jax.profiler.TraceAnnotation("dedup.x.y"):
+                pass
+        """, hot=False)
+    assert [f.rule for f in found] == ["tracing-choke-point"] * 2
+    # the one choke point itself is exempt
+    from repro import tracing
+    with open(tracing.__file__) as f:
+        assert "jax.profiler.TraceAnnotation" in f.read()
+    assert lint_sources([tracing.__file__],
+                        rules=["tracing-choke-point"]) == []
+
+
 def test_source_rule_tracer_branch(tmp_path):
     found = _lint_snippet(tmp_path, """\
         import jax.numpy as jnp
