@@ -296,6 +296,127 @@ _register(Rule(
     _reduce_applies, _reduce_check))
 
 
+# -- no-filter-sized-pass --------------------------------------------------
+# The bitset step changes only the words a batch touches, in place (DESIGN
+# §3.2): no zero-filled delta, no relayout, no elementwise combine. So in
+# the optimized HLO no instruction outside a fusion body may produce a
+# buffer of the filter's words, save the state passing through (parameter,
+# tuple, get-tuple-element, bitcast, the scan's while) and the in-place
+# scatters (a scatter, or a fusion whose root is one). An undonated step
+# may copy its input parameters into the buffers it returns.
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{\s*$")
+_ENTRY_RE = re.compile(r"^ENTRY\s+%?([\w.\-]+)", re.M)
+_PASS_THROUGH = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                 "while", "scatter"}
+
+
+def _split_type(rest: str) -> Tuple[str, str]:
+    """'u32[2,8]{1,0} op(...' or '(s32[], u32[8]) op(...' -> (type, tail)."""
+    if not rest.startswith("("):
+        ty, _, tail = rest.partition(" ")
+        return ty, tail
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += {"(": 1, ")": -1}.get(ch, 0)
+        if depth == 0:
+            return rest[:i + 1], rest[i + 1:]
+    return rest, ""
+
+
+def hlo_instructions(hlo: str) -> List[Tuple[str, str, str, str, str]]:
+    """(computation, name, type, opcode, line) of every instruction in the
+    HLO text, in order."""
+    out, comp = [], ""
+    for line in hlo.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        ty, tail = _split_type(line[m.end():])
+        op = _OPCODE_RE.match(tail)
+        if op:
+            out.append((comp, m.group(1), ty, op.group(1), line.strip()))
+    return out
+
+
+def _elements(ty: str) -> int:
+    """Largest element count among the array shapes of an HLO type."""
+    best = 0
+    for shape in _SHAPE_RE.findall(ty):
+        n = 1
+        for d in shape.split(","):
+            if d:
+                n *= int(d)
+        best = max(best, n)
+    return best
+
+
+def filter_sized_passes(hlo: str, filter_elems: int,
+                        donated: bool) -> List[str]:
+    """Instructions outside fusion bodies that produce a buffer of at least
+    ``filter_elems`` elements and are not the state passing through or an
+    in-place scatter (see the rule's comment)."""
+    instrs = hlo_instructions(hlo)
+    fused = {c for _, _, _, op, line in instrs if op == "fusion"
+             for c in _CALLS_RE.findall(line)}
+    roots = {comp: op for comp, _, _, op, line in instrs
+             if line.startswith("ROOT ")}
+    entry = _ENTRY_RE.search(hlo)
+    params = {name for comp, name, _, op, _ in instrs if op == "parameter"
+              and entry and comp == entry.group(1)}
+    bad = []
+    for comp, name, ty, op, line in instrs:
+        if comp in fused or _elements(ty) < filter_elems:
+            continue
+        if op in _PASS_THROUGH:
+            continue
+        if op == "fusion" and any(roots.get(c) == "scatter"
+                                  for c in _CALLS_RE.findall(line)):
+            continue
+        if op == "copy" and not donated:
+            src = _OPERAND_RE.findall(line.partition("copy(")[2])[:1]
+            if src and src[0] in params:
+                continue
+        bad.append(f"{op} {name} {ty}")
+    return bad
+
+
+def _pass_applies(ep) -> bool:
+    cfg = ep.cfg
+    if cfg is None or not ep.extra.get("separable", False):
+        return False
+    from ..core.sketch import get_spec
+    return (cfg.is_planes and cfg.backend == "jnp"
+            and get_spec(cfg.variant).family == "bitset"
+            and bool({"step", "stream"} & ep.tags))
+
+
+def _pass_check(t: Target) -> List[Finding]:
+    bad = filter_sized_passes(t.compiled_text(), t.entry.extra["filter_elems"],
+                              "donated" in t.entry.tags)
+    if bad:
+        return _find("no-filter-sized-pass", t.entry.name,
+                     f"{len(bad)} filter-sized buffers made by the step "
+                     f"({'; '.join(bad[:4])}) — the update should "
+                     f"read-modify-write only the touched words in place")
+    return []
+
+
+_register(Rule(
+    "no-filter-sized-pass",
+    "the jnp bitset step makes no filter-sized buffer: only the state "
+    "passing through and the in-place scatters of the touched words "
+    "(DESIGN §3.2)",
+    _pass_applies, _pass_check))
+
+
 # -- state-donated-and-aliased ---------------------------------------------
 # Every donated state leaf — filter planes, position, load, rng, the swbf
 # window ring, the elastic router table — must appear in the compiled

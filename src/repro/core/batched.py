@@ -42,11 +42,10 @@ import jax.numpy as jnp
 from .config import DedupConfig
 from .hashing import derive_seeds, hash_positions, uniform_positions
 from .packed import (clamped_run_counts, count_planes_from_sorted,
-                     delta_from_sorted_positions, planes_nonzero,
-                     planes_saturating_add, planes_saturating_sub,
-                     planes_set_value, popcount, probe_cell_values,
-                     probe_packed, probe_sorted_packed, run_heads,
-                     run_heads_1d, split_pos)
+                     planes_nonzero, planes_saturating_add,
+                     planes_saturating_sub, planes_set_value, popcount,
+                     probe_cell_values, probe_packed, run_heads,
+                     run_heads_1d, split_pos, update_sorted_positions)
 from .state import FilterState, WindowRing
 
 
@@ -544,7 +543,7 @@ def make_bitset_step(cfg: DedupConfig, spec, apply=None) -> BatchedStep:
     draw; probe/scatter/load are the family-shared machinery. rsbf, bsbf,
     bsbfsd and rlbsbf are all THIS function under different specs.
 
-    ``apply`` (plane layout only) replaces the filter-sized update:
+    ``apply`` (plane layout only) replaces the touched-word update:
     ``apply(bits (k, W), load, spi, spd) -> (new bits, new load)`` from the
     (k, B) sorted uint32 insert/delete positions. The fused Pallas step
     passes its kernel here, so everything before the update is this very
@@ -566,27 +565,24 @@ def make_bitset_step(cfg: DedupConfig, spec, apply=None) -> BatchedStep:
             return probe_packed(bits, pos)                        # (B, k)
         return bits[rows[None, :], pos]
 
-    def probe_sorted(bits, sp):
-        """Row-aligned probe of (k, B) sorted positions; sentinels clamp and
-        must be masked by the caller (load_delta_from_sorted does)."""
-        if cfg.is_planes:
-            return probe_sorted_packed(bits, sp)
-        return bits[rows[:, None], jnp.minimum(sp, s - 1)]
-
     def apply_updates(bits, pos, ins_mask, del_pos, del_mask, spi, spd):
         """Deletions from the snapshot, then insertions (insertions win):
-        R = (A & ~D) | I. Packed builds both deltas from the already-sorted
-        positions and applies them in ONE elementwise pass."""
+        R = (A & ~D) | I -> (new bits, (k,) exact load delta). Packed
+        read-modify-writes only the touched words, in place, and counts
+        the load from the words it read (DESIGN.md §3.2)."""
         if cfg.is_planes:
-            W = bits.shape[1]
-            delta_i = delta_from_sorted_positions(spi, W)
-            delta_d = delta_from_sorted_positions(spd, W)
-            return (bits & ~delta_d) | delta_i
+            return update_sorted_positions(bits, spi, spd)
         dp = jnp.where(del_mask, del_pos, s)
-        bits = bits.at[rows[None, :], dp].set(0, mode="drop")
+        new = bits.at[rows[None, :], dp].set(0, mode="drop")
         ip = jnp.where(ins_mask, pos, s)
-        bits = bits.at[rows[None, :], ip].set(1, mode="drop")
-        return bits
+        new = new.at[rows[None, :], ip].set(1, mode="drop")
+
+        def at(b, sp):
+            # row-aligned probe; load_delta_from_sorted masks the sentinels
+            return b[rows[:, None], jnp.minimum(sp, s - 1)]
+
+        return new, load_delta_from_sorted(spi, at(bits, spi), spd,
+                                           at(bits, spd), at(new, spd), s)
 
     def recompute_load(bits):
         # debug escape hatch only — O(s) reduce over the whole filter
@@ -610,16 +606,12 @@ def make_bitset_step(cfg: DedupConfig, spec, apply=None) -> BatchedStep:
             new = FilterState(bits, state.position
                               + valid.sum(dtype=jnp.int32), load, rng)
             return new, BatchResult(dup=dup, inserted=insert)
-        bits = apply_updates(state.bits, pos, ins_mask, rnd.del_pos, del_mask,
-                             spi, spd)
+        bits, delta = apply_updates(state.bits, pos, ins_mask, rnd.del_pos,
+                                    del_mask, spi, spd)
         if cfg.debug_exact_load:
             load = recompute_load(bits)
         else:
-            pre_i = probe_sorted(state.bits, spi)                 # pre-update
-            pre_d = probe_sorted(state.bits, spd)
-            post_d = probe_sorted(bits, spd)                      # post-update
-            load = state.load + load_delta_from_sorted(
-                spi, pre_i, spd, pre_d, post_d, s)
+            load = state.load + delta
         n_valid = valid.sum(dtype=jnp.int32)
         new = FilterState(bits, state.position + n_valid, load, rng)
         return new, BatchResult(dup=dup, inserted=insert)

@@ -6,11 +6,14 @@ performs:
 
   * probe:   word gather (lowers to dynamic-slice) + mask test — multi-plane
     states OR their planes' gathered words first (nonzero test)
-  * set/clear scatter: sort the batch's word indices, OR together the
-    single-bit masks of each equal-index run with one segmented scan, and
-    scatter exactly one uint32 per touched word (``_bit_delta_rows``). This is
-    O(B log B) work and O(B) scatter entries — no per-bit decomposition, no
-    (B·k, 32) uint8 intermediate (DESIGN.md §3.2).
+  * set/clear: sort the batch's word indices, OR together the single-bit
+    masks of each equal-index run with one segmented scan, and
+    read-modify-write exactly one uint32 per touched word, in place
+    (``update_sorted_positions``). This is O(B log B) work and O(B) gather
+    and scatter entries — no per-bit decomposition, no (B·k, 32) uint8
+    intermediate, no filter-sized buffer (DESIGN.md §3.2). The dense
+    ``(k, W)`` delta form (``delta_from_sorted_positions``) feeds the Pallas
+    kernel and the tests.
   * counter arithmetic (DESIGN.md §3.6): saturating increment/decrement and
     set-to-value expressed as carry/borrow chains of the same
     ``(A & ~D) | I`` word ops — ``planes_saturating_sub/add``,
@@ -23,6 +26,8 @@ explicit VMEM tiling; these jnp forms are their oracles and the fallback path.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +35,7 @@ import numpy as np
 __all__ = [
     "pack_bits", "unpack_bits", "split_pos", "probe_packed",
     "probe_cell_values",
-    "delta_from_sorted_positions", "probe_sorted_packed",
+    "delta_from_sorted_positions", "update_sorted_positions",
     "scatter_or", "scatter_andnot", "popcount", "popcount_words",
     "pack_cells", "unpack_cells", "planes_nonzero",
     "count_field_chunks", "counts_to_planes",
@@ -114,19 +119,27 @@ def run_heads(sp: jnp.ndarray) -> jnp.ndarray:
         [jnp.ones((k, 1), bool), sp[:, 1:] != sp[:, :-1]], axis=1)
 
 
-def _scatter_run_or(sw: jnp.ndarray, sm: jnp.ndarray, W: int) -> jnp.ndarray:
-    """(k, B) *sorted* word indices + aligned masks -> (k, W) uint32 delta:
-    segmented-OR each equal-index run, scatter one word per run tail.
-    Indices >= W (disabled-lane sentinels) are dropped by the scatter."""
+def _run_tails(sw: jnp.ndarray, sm: jnp.ndarray, W: int):
+    """(k, B) *sorted* word indices + aligned masks -> (tail word index,
+    OR mask), both (k, B): segmented-OR each equal-index run; a run's tail
+    holds the union mask of its word, every other lane and every
+    disabled-lane sentinel (index >= W) carries index W. No (k, W) buffer
+    is made — the caller scatters (or read-modify-writes) the tails."""
     k = sw.shape[0]
-    head = run_heads(sw)
-    acc = _segmented_or(head, sm)
+    acc = _segmented_or(run_heads(sw), sm)
     tail = jnp.concatenate(
         [sw[:, :-1] != sw[:, 1:], jnp.ones((k, 1), bool)], axis=1)
-    idx = jnp.where(tail, sw, W)                             # non-tails dropped
+    return jnp.where(tail & (sw < W), sw, W), acc
+
+
+def _scatter_run_or(sw: jnp.ndarray, sm: jnp.ndarray, W: int) -> jnp.ndarray:
+    """(k, B) *sorted* word indices + aligned masks -> (k, W) uint32 delta:
+    one word per run tail. Indices >= W (disabled-lane sentinels) are
+    dropped by the scatter."""
+    k = sw.shape[0]
+    idx, acc = _run_tails(sw, sm, W)
     rows = jnp.arange(k, dtype=jnp.int32)[:, None]
-    return jnp.zeros((k, W), jnp.uint32).at[rows, idx].set(
-        jnp.where(tail, acc, jnp.uint32(0)), mode="drop")
+    return jnp.zeros((k, W), jnp.uint32).at[rows, idx].set(acc, mode="drop")
 
 
 def _bit_delta_rows(W: int, w_idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
@@ -146,6 +159,14 @@ def _bit_delta_rows(W: int, w_idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarra
     return _scatter_run_or(sw, sm, W)
 
 
+def _sorted_words(sp: jnp.ndarray):
+    """(k, B) sorted bit positions -> (word index int32, single-bit mask);
+    a sentinel position >= 32*W gives a word index >= W."""
+    sw = (sp >> 5).astype(jnp.int32)
+    sm = (_BIT << (sp & 31).astype(jnp.uint32)).astype(jnp.uint32)
+    return sw, sm
+
+
 def delta_from_sorted_positions(sp: jnp.ndarray, W: int) -> jnp.ndarray:
     """(k, B) *sorted* bit positions -> (k, W) uint32 OR-union delta.
 
@@ -154,23 +175,65 @@ def delta_from_sorted_positions(sp: jnp.ndarray, W: int) -> jnp.ndarray:
     permutation), OR-combined per word run with one segmented scan, and
     scattered one uint32 per touched word. Disabled lanes must carry a
     sentinel position >= 32*W: their word index lands at W and the scatter
-    drops it. This is the hot-path delta builder (DESIGN.md §3.2).
+    drops it. The dense form: the fused Pallas kernel's operand and the
+    test oracle of ``update_sorted_positions`` (DESIGN.md §3.2).
     """
-    sw = (sp >> 5).astype(jnp.int32)                         # sentinel -> >= W
-    sm = (_BIT << (sp & 31).astype(jnp.uint32)).astype(jnp.uint32)
-    return _scatter_run_or(sw, sm, W)
+    return _scatter_run_or(*_sorted_words(sp), W)
 
 
-def probe_sorted_packed(words: jnp.ndarray, sp: jnp.ndarray) -> jnp.ndarray:
-    """Row-aligned probe: words (k, W), sp (k, B) positions (row f probes its
-    own row — unlike ``probe_packed``'s (B, k) element-major layout).
-    Sentinel positions read a clamped word; mask the result with ``sp < s``.
-    """
+def update_sorted_positions(words: jnp.ndarray, spi: jnp.ndarray,
+                            spd: jnp.ndarray):
+    """R = (A & ~D) | I on only the words the batch touches, in place.
+
+    words (k, W) uint32; spi / spd (k, B) *sorted* insert / delete bit
+    positions, disabled lanes at a sentinel >= 32*W. Each word run's tail
+    reads its word, clears (deletes) or sets (inserts) the run's union
+    mask, and is scattered back: deletes first, then inserts read the
+    post-delete words, so a word hit by both ends as (A & ~D) | I — the
+    dense ``delta_from_sorted_positions`` algebra, word for word.
+
+    Returns (new words, (k,) int32 exact load delta): the popcount change
+    of the touched words, from the same gathered words. Every read of the
+    filter feeds a scatter's operands, so XLA orders it before the write
+    and updates a donated filter in place — no (k, W) temporary
+    (DESIGN.md §3.2).
+
+    XLA lowers the scatter onto a 1-D view of the filter. That view is
+    taken in the platform's own memory order of a (k, W) array, so it is a
+    bitcast: row-major by default, lane-major on the TPU, whose tiles hold
+    128 consecutive words of each row side by side. Where 128 does not
+    divide W the TPU's tiles are padded, no view is a bitcast, and the
+    row-major one is kept."""
+    W = words.shape[1]
+    return jax.lax.platform_dependent(
+        words, spi, spd,
+        tpu=functools.partial(_update_touched,
+                              lane=128 if W % 128 == 0 else W),
+        default=functools.partial(_update_touched, lane=W))
+
+
+def _update_touched(words, spi, spd, *, lane: int):
+    """``update_sorted_positions`` on the flat view in which word w of row
+    r sits at (w // lane, r, w % lane)."""
     k, W = words.shape
-    rows = jnp.arange(k, dtype=jnp.int32)[:, None]
-    sw = jnp.minimum((sp >> 5).astype(jnp.int32), W - 1)
-    got = words[rows, sw]
-    return ((got >> (sp & 31).astype(jnp.uint32)) & _BIT).astype(jnp.uint8)
+    n = k * W
+    flat = words.reshape(k, W // lane, lane).transpose(1, 0, 2).reshape(n)
+    row = jnp.arange(k, dtype=jnp.int32)[:, None] * lane
+
+    def slot(w):            # (k, B) word index, W = dropped -> flat index
+        return (w // lane) * (k * lane) + row + w % lane
+
+    di, dm = _run_tails(*_sorted_words(spd), W)
+    ii, im = _run_tails(*_sorted_words(spi), W)
+    fd, fi = slot(di), slot(ii)
+    pre = flat[jnp.minimum(fd, n - 1)]
+    flat = flat.at[fd].set(pre & ~dm, mode="drop")
+    mid = flat[jnp.minimum(fi, n - 1)]
+    flat = flat.at[fi].set(mid | im, mode="drop")
+    words = flat.reshape(W // lane, k, lane).transpose(1, 0, 2).reshape(k, W)
+    lost = jnp.where(di < W, popcount_words(pre & dm), 0).sum(axis=-1)
+    gained = jnp.where(ii < W, popcount_words(im & ~mid), 0).sum(axis=-1)
+    return words, (gained - lost).astype(jnp.int32)
 
 
 def scatter_or(words: jnp.ndarray, w_idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

@@ -75,6 +75,93 @@ def test_no_filter_sized_reduce_fires_on_debug_exact_load():
         "no-filter-sized-reduce::step/rlbsbf/planes/jnp/debug-exact-load"]
 
 
+def _dense_delta_step_entry(cfg):
+    """The deliberately-dense mini: the bitset step with the dense delta
+    update, ``(A & ~delta(spd)) | delta(spi)``, riding its ``apply`` hook."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import get_spec
+    from repro.core.batched import make_bitset_step
+    from repro.core.packed import delta_from_sorted_positions, popcount
+    from repro.core.state import init_state
+
+    def dense(bits, load, spi, spd):
+        w = bits.shape[1]
+        new = ((bits & ~delta_from_sorted_positions(spd, w))
+               | delta_from_sorted_positions(spi, w))
+        return new, popcount(new)
+
+    def build():
+        step = make_bitset_step(cfg, get_spec(cfg.variant), apply=dense)
+        st = jax.eval_shape(functools.partial(init_state, cfg))
+        b = cfg.batch_size
+        return jax.jit(step, donate_argnums=0).lower(
+            st, jax.ShapeDtypeStruct((b,), jnp.uint32),
+            jax.ShapeDtypeStruct((b,), jnp.bool_))
+
+    ok = step_entry(cfg)
+    return EntryPoint(name="mini/dense-delta", tags=frozenset({"step",
+                      "donated"}), cfg=cfg, build=build, extra=ok.extra)
+
+
+def test_filter_sized_pass_rule_fires_on_dense_delta_step():
+    """The dense delta update builds two zero-filled (k, W) deltas and
+    combines them with the filter: the rule reports it (the real step
+    passes, ``tests/test_hlo_step.py``)."""
+    found = lint_entry(_dense_delta_step_entry(_canon_cfg("rlbsbf", "planes")),
+                       rules=["no-filter-sized-pass"])
+    assert [f.key for f in found] == [
+        "no-filter-sized-pass::mini/dense-delta"]
+
+
+@pytest.mark.parametrize("donated", [True, False],
+                         ids=["donated", "undonated"])
+def test_filter_sized_pass_rule_on_synthetic_hlo(donated):
+    """Pins the textual contract: the state passing through and a fusion
+    whose root is a scatter pass; a filter-sized elementwise fusion fires;
+    a copy of an entry parameter passes only where the state is not
+    donated; fusion bodies are not scanned."""
+    hlo = textwrap.dedent("""\
+        HloModule jit_s
+
+        %scatter_comp.1 (p0: u32[2,4096], p1: s32[8,2], p2: u32[8]) -> u32[2,4096] {
+          %p0 = u32[2,4096]{1,0} parameter(0)
+          %inner = u32[2,4096]{1,0} add(%p0, %p0)
+          ROOT %scatter.1 = u32[2,4096]{1,0} scatter(%p0, %p1, %p2), to_apply=%r
+        }
+
+        %and_or_comp.2 (q0: u32[2,4096], q1: u32[2,4096]) -> u32[2,4096] {
+          %q0 = u32[2,4096]{1,0} parameter(0)
+          ROOT %or.1 = u32[2,4096]{1,0} or(%q0, %q0)
+        }
+
+        ENTRY %main.1 (bits: u32[2,4096], keys: u32[8]) -> (u32[2,4096], pred[8]) {
+          %bits = u32[2,4096]{1,0} parameter(0)
+          %keys = u32[8]{0} parameter(1)
+          %copy.1 = u32[2,4096]{1,0} copy(%bits)
+          %fusion.1 = u32[2,4096]{1,0} fusion(%copy.1, %i, %u), kind=kLoop, calls=%scatter_comp.1
+          %bc = u32[8192]{0} bitcast(%fusion.1)
+          %w = (s32[], u32[2,4096]{1,0}) while((s32[], u32[2,4096]{1,0}) %t), condition=%c, body=%b
+          %and_or_fusion = u32[2,4096]{1,0} fusion(%fusion.1, %fusion.1), kind=kLoop, calls=%and_or_comp.2
+          ROOT %out = (u32[2,4096]{1,0}, pred[8]{0}) tuple(%and_or_fusion, %p)
+        }
+        """)
+    tags = ("step", "donated") if donated else ("step",)
+    ep = _fake_entry("mini/pass", tags=tags,
+                     cfg=_canon_cfg("rlbsbf", "planes"),
+                     extra={"filter_elems": 4096, "separable": True})
+    found = lint_entry(ep, rules=["no-filter-sized-pass"],
+                       target=Target(ep, compiled_text=hlo))
+    assert [f.rule for f in found] == ["no-filter-sized-pass"]
+    detail = found[0].detail
+    assert "and_or_fusion" in detail and "scatter" not in detail
+    assert "inner" not in detail and "while" not in detail
+    assert ("copy.1" in detail) == donated
+
+
 def test_donation_rule_fires_on_undonated_stream():
     """stream_entry(donate=False) is the deliberately-broken twin: same
     scan, state NOT donated, so no alias table entry covers the filter."""
@@ -366,6 +453,7 @@ def test_cli_list_names_every_rule():
         [sys.executable, "-m", "repro.analysis", "--list"],
         capture_output=True, text=True)
     assert out.returncode == 0
+    assert "no-filter-sized-pass" in HLO_RULES
     for name in list(HLO_RULES) + list(SOURCE_RULES):
         assert name in out.stdout
 

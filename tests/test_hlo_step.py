@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import lint_entry, reduce_operand_dims
-from repro.analysis.entrypoints import step_entry, stream_entry
+from repro.analysis.entrypoints import _canon_cfg, step_entry, stream_entry
 from repro.analysis.hlo_lint import Target
 from repro.core import Dedup, DedupConfig
 from repro.core.engine import get_engine
@@ -37,6 +37,17 @@ def test_no_filter_sized_reduce_in_steady_state_step():
     ep = _step_target(cfg)
     assert ep.extra["separable"]       # thresholds separated by construction
     assert lint_entry(ep, rules=["no-filter-sized-reduce"]) == []
+
+
+def test_canonical_bitset_step_and_stream_make_no_filter_sized_pass():
+    """The sweep's canonical 2^20-bit bitset step (undonated: it may copy
+    its input once) and its donated stream update only the touched words
+    in place: no zero-filled delta, no relayout, no elementwise combine
+    (DESIGN §3.2)."""
+    cfg = _canon_cfg("rlbsbf", "planes")
+    assert cfg.memory_bits == 1 << 20
+    for ep in (step_entry(cfg), stream_entry(cfg)):
+        assert lint_entry(ep, rules=["no-filter-sized-pass"]) == [], ep.name
 
 
 def test_debug_exact_load_does_popcount_reduce():

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.hlo_lint import filter_sized_passes
 from repro.configs.paper_dedup import paper_config
-from repro.core import DedupConfig
+from repro.core import Dedup, DedupConfig
 from repro.core.batched import make_templated_step
 from repro.core.state import init_state
 from repro.kernels.common import VMEM_FILTER_BYTES_LIMIT, fused_resident_bytes
@@ -94,3 +95,29 @@ def test_jnp_step_compiles_at_paper_512mb(one_chip, no_persistent_cache):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes >= 512 * 2**20
     assert total < CHIP_HBM_BYTES
+
+
+@pytest.mark.parametrize("entry", ["step", "stream"])
+def test_jnp_bitset_update_in_place_on_v5e(one_chip, no_persistent_cache,
+                                            entry):
+    """At the paper's 512 MB RLBSBF filter, the donated step and the
+    donated stream compiled for one v5e make no filter-sized buffer: the
+    touched-word scatters run on a flat view that is a bitcast of the
+    chip's tiled (k, W) layout, so they update the filter in place (DESIGN
+    §3.2)."""
+    cfg = paper_config("rlbsbf", 512, layout="planes")
+    if entry == "step":
+        compiled = _compile(make_templated_step(cfg), cfg, one_chip)
+    else:
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                             jax.eval_shape(lambda: init_state(cfg)))
+        b = cfg.batch_size
+        compiled = Dedup(cfg)._stream.lower(
+            state, sds((2, b), jnp.uint32), sds((2, b), jnp.bool_)).compile()
+    assert filter_sized_passes(compiled.as_text(), cfg.s_words,
+                               donated=True) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < cfg.s_words * 4 // 8
