@@ -17,8 +17,9 @@ that true as the code grows:
     ``fused_counter_step.py`` are deprecation shims; new src code imports
     ``kernels.fused_template``.
   * ``tracing-choke-point`` — profiler annotations (the program's spans)
-    are made only in ``repro/tracing.py`` (DESIGN §7), so every span lands
-    in one registry under one naming scheme and no second span system
+    and ``jax.named_scope`` (its device scopes) are made only in
+    ``repro/tracing.py`` (DESIGN §7), so every span lands in one registry
+    and every scope under one naming scheme, and no second span system
     grows.
   * ``no-python-branch-on-tracer`` — an ``if``/``while`` on a local
     assigned from a jnp/lax/random call inside a hot module is a trace
@@ -67,6 +68,8 @@ SHIM_EXEMPT = ("kernels/fused_step.py", "kernels/fused_counter_step.py")
 
 # the span-making surfaces of jax.profiler, under any import spelling
 TRACE_ATTRS = ("TraceAnnotation", "StepTraceAnnotation", "annotate_function")
+# the scope-making surface of jax, under any import spelling
+SCOPE_ATTRS = ("named_scope",)
 TRACING_EXEMPT = ("repro/tracing.py",)
 
 HOST_SYNC_ATTRS = ("block_until_ready", "device_get", "item")
@@ -167,23 +170,28 @@ def _check_tracing(relpath: str, tree: ast.AST, text: str, hot: bool
     for node in ast.walk(tree):
         used = None
         if isinstance(node, ast.ImportFrom) and node.module and \
-                node.module.endswith("profiler"):
-            used = next((a.name for a in node.names
-                         if a.name in TRACE_ATTRS), None)
-        elif isinstance(node, ast.Attribute) and node.attr in TRACE_ATTRS:
+                node.module.split(".")[0] == "jax":
+            attrs = SCOPE_ATTRS + (TRACE_ATTRS if node.module.endswith(
+                "profiler") else ())
+            used = next((a.name for a in node.names if a.name in attrs),
+                        None)
+        elif isinstance(node, ast.Attribute) and \
+                node.attr in TRACE_ATTRS + SCOPE_ATTRS:
             used = dotted_name(node) or node.attr
         if used:
+            helper = ("repro.tracing.scope" if used.endswith(SCOPE_ATTRS)
+                      else "repro.tracing.span")
             findings.append(Finding(
                 "tracing-choke-point", f"{relpath}::{used}",
-                f"line {node.lineno}: `{used}` — make spans with "
-                f"repro.tracing.span (DESIGN §7)"))
+                f"line {node.lineno}: `{used}` — make it with {helper} "
+                f"(DESIGN §7)"))
     return findings
 
 
 _register(SourceRule(
     "tracing-choke-point",
-    "profiler annotations are only made in repro/tracing.py, the one "
-    "span registry (DESIGN §7)",
+    "profiler annotations and named scopes are only made in "
+    "repro/tracing.py, the one span registry (DESIGN §7)",
     _check_tracing))
 
 

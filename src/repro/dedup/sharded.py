@@ -79,7 +79,7 @@ from ..core.sketch import get_spec
 from ..core.state import (FilterState, RouterState, WindowRing, init_router,
                           init_state)
 from ..distributed.sharding import rebalance_collect
-from ..tracing import span
+from ..tracing import scope, span
 
 _INT32_MAX = np.iinfo(np.int32).max
 
@@ -312,36 +312,44 @@ class ShardedDedup:
         def local_fn(state: FilterState, keys: jnp.ndarray,
                      valid: jnp.ndarray):
             state = jax.tree.map(lambda x: x[0], state)
-            owner = route_hash(keys, n_shards, seed)            # (b,)
-            onehot = (valid[:, None] &
-                      (owner[:, None] ==
-                       jnp.arange(n_shards, dtype=jnp.int32)[None, :]))
-            pos_in = jnp.cumsum(onehot, axis=0) - 1              # (b, S)
-            my_pos = jnp.take_along_axis(
-                pos_in, owner[:, None], axis=1)[:, 0]            # (b,)
-            keep = valid & (my_pos < cap)
-            overflow = jnp.sum(valid & ~keep)
-            # dispatch buffers (S, C)
-            send_keys = jnp.zeros((n_shards, cap), jnp.uint32)
-            send_valid = jnp.zeros((n_shards, cap), bool)
-            o = jnp.where(keep, owner, n_shards)                 # drop overflow
-            p = jnp.where(keep, my_pos, 0)
-            send_keys = send_keys.at[o, p].set(keys, mode="drop")
-            send_valid = send_valid.at[o, p].set(True, mode="drop")
+            with scope("dedup.sharded.route"):
+                owner = route_hash(keys, n_shards, seed)        # (b,)
+                onehot = (valid[:, None] &
+                          (owner[:, None] ==
+                           jnp.arange(n_shards, dtype=jnp.int32)[None, :]))
+                pos_in = jnp.cumsum(onehot, axis=0) - 1          # (b, S)
+                my_pos = jnp.take_along_axis(
+                    pos_in, owner[:, None], axis=1)[:, 0]        # (b,)
+                keep = valid & (my_pos < cap)
+                overflow = jnp.sum(valid & ~keep)
+                # dispatch buffers (S, C)
+                send_keys = jnp.zeros((n_shards, cap), jnp.uint32)
+                send_valid = jnp.zeros((n_shards, cap), bool)
+                o = jnp.where(keep, owner, n_shards)             # drop overflow
+                p = jnp.where(keep, my_pos, 0)
+                send_keys = send_keys.at[o, p].set(keys, mode="drop")
+                send_valid = send_valid.at[o, p].set(True, mode="drop")
             # exchange: rows become per-source buffers for my shard
-            recv_keys = jax.lax.all_to_all(
-                send_keys, all_axes, split_axis=0, concat_axis=0, tiled=True)
-            recv_valid = jax.lax.all_to_all(
-                send_valid, all_axes, split_axis=0, concat_axis=0, tiled=True)
+            with scope("dedup.sharded.exchange"):
+                recv_keys = jax.lax.all_to_all(
+                    send_keys, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
+                recv_valid = jax.lax.all_to_all(
+                    send_valid, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
             # local dedup over everything I own this step
-            flat_keys = recv_keys.reshape(-1)
-            flat_valid = recv_valid.reshape(-1)
-            state, res = step(state, flat_keys, flat_valid)
-            dup_buf = res.dup.reshape(n_shards, cap)
+            with scope("dedup.sharded.step"):
+                flat_keys = recv_keys.reshape(-1)
+                flat_valid = recv_valid.reshape(-1)
+                state, res = step(state, flat_keys, flat_valid)
+                dup_buf = res.dup.reshape(n_shards, cap)
             # verdicts home
-            back = jax.lax.all_to_all(
-                dup_buf, all_axes, split_axis=0, concat_axis=0, tiled=True)
-            dup = back[o.clip(0, n_shards - 1), p] & keep        # overflow -> distinct
+            with scope("dedup.sharded.return"):
+                back = jax.lax.all_to_all(
+                    dup_buf, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
+                # overflow -> distinct
+                dup = back[o.clip(0, n_shards - 1), p] & keep
             state = jax.tree.map(lambda x: x[None], state)
             return state, dup, overflow[None].astype(jnp.int32)
 
@@ -569,25 +577,29 @@ class ShardedDedup:
         def dispatch(state: FilterState, keys: jnp.ndarray,
                      valid: jnp.ndarray) -> InFlight:
             del state                        # static routing reads no state
-            owner = route_hash(keys, n_shards, seed)
-            onehot = (valid[:, None] &
-                      (owner[:, None] ==
-                       jnp.arange(n_shards, dtype=jnp.int32)[None, :]))
-            pos_in = jnp.cumsum(onehot, axis=0) - 1
-            my_pos = jnp.take_along_axis(
-                pos_in, owner[:, None], axis=1)[:, 0]
-            keep = valid & (my_pos < cap)
-            overflow = jnp.sum(valid & ~keep)
-            o = jnp.where(keep, owner, n_shards)
-            p = jnp.where(keep, my_pos, 0)
-            send_keys = jnp.zeros((n_shards, cap), jnp.uint32
-                                  ).at[o, p].set(keys, mode="drop")
-            send_cnt = jnp.sum(onehot & keep[:, None], axis=0,
-                               dtype=jnp.int32)                  # (S,)
-            recv_keys = jax.lax.all_to_all(
-                send_keys, all_axes, split_axis=0, concat_axis=0, tiled=True)
-            recv_cnt = jax.lax.all_to_all(
-                send_cnt, all_axes, split_axis=0, concat_axis=0, tiled=True)
+            with scope("dedup.sharded.route"):
+                owner = route_hash(keys, n_shards, seed)
+                onehot = (valid[:, None] &
+                          (owner[:, None] ==
+                           jnp.arange(n_shards, dtype=jnp.int32)[None, :]))
+                pos_in = jnp.cumsum(onehot, axis=0) - 1
+                my_pos = jnp.take_along_axis(
+                    pos_in, owner[:, None], axis=1)[:, 0]
+                keep = valid & (my_pos < cap)
+                overflow = jnp.sum(valid & ~keep)
+                o = jnp.where(keep, owner, n_shards)
+                p = jnp.where(keep, my_pos, 0)
+                send_keys = jnp.zeros((n_shards, cap), jnp.uint32
+                                      ).at[o, p].set(keys, mode="drop")
+                send_cnt = jnp.sum(onehot & keep[:, None], axis=0,
+                                   dtype=jnp.int32)              # (S,)
+            with scope("dedup.sharded.exchange"):
+                recv_keys = jax.lax.all_to_all(
+                    send_keys, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
+                recv_cnt = jax.lax.all_to_all(
+                    send_cnt, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
             return InFlight(recv_keys[None], recv_cnt[None], o[None], None,
                             p[None], keep[None],
                             overflow[None].astype(jnp.int32))
@@ -595,29 +607,34 @@ class ShardedDedup:
         def consume(state: FilterState, fl: InFlight):
             state = jax.tree.map(lambda x: x[0], state)
             rk, cnt = fl.keys[0], fl.cnt[0]
-            lanes = jnp.arange(cap, dtype=jnp.int32)[None, :]
-            vmask = lanes < cnt[:, None]                     # (S, C)
-            if t_width < flat:
-                # owner-side compaction: rank = lanes before me, globally
-                offs = jnp.cumsum(cnt) - cnt                 # exclusive
-                rankm = offs[:, None] + lanes                # (S, C)
-                ok = vmask & (rankm < t_width)
-                rank_overflow = jnp.sum(vmask & ~ok)
-                tgt = jnp.where(ok, rankm, t_width)
-                ck = jnp.zeros((t_width,), jnp.uint32
-                               ).at[tgt.reshape(-1)].set(
-                                   rk.reshape(-1), mode="drop")
-                cvalid = (jnp.arange(t_width, dtype=jnp.int32)
-                          < jnp.minimum(cnt.sum(), t_width))
-                state, res = step(state, ck, cvalid)
-                dup_buf = res.dup[jnp.minimum(rankm, t_width - 1)] & ok
-            else:
-                rank_overflow = jnp.int32(0)
-                state, res = step(state, rk.reshape(-1), vmask.reshape(-1))
-                dup_buf = res.dup.reshape(n_shards, cap)
-            back = jax.lax.all_to_all(
-                dup_buf, all_axes, split_axis=0, concat_axis=0, tiled=True)
-            dup = back[fl.o[0].clip(0, n_shards - 1), fl.p[0]] & fl.keep[0]
+            with scope("dedup.sharded.step"):
+                lanes = jnp.arange(cap, dtype=jnp.int32)[None, :]
+                vmask = lanes < cnt[:, None]                 # (S, C)
+                if t_width < flat:
+                    # owner-side compaction: rank = lanes before me
+                    offs = jnp.cumsum(cnt) - cnt             # exclusive
+                    rankm = offs[:, None] + lanes            # (S, C)
+                    ok = vmask & (rankm < t_width)
+                    rank_overflow = jnp.sum(vmask & ~ok)
+                    tgt = jnp.where(ok, rankm, t_width)
+                    ck = jnp.zeros((t_width,), jnp.uint32
+                                   ).at[tgt.reshape(-1)].set(
+                                       rk.reshape(-1), mode="drop")
+                    cvalid = (jnp.arange(t_width, dtype=jnp.int32)
+                              < jnp.minimum(cnt.sum(), t_width))
+                    state, res = step(state, ck, cvalid)
+                    dup_buf = res.dup[jnp.minimum(rankm, t_width - 1)] & ok
+                else:
+                    rank_overflow = jnp.int32(0)
+                    state, res = step(state, rk.reshape(-1),
+                                      vmask.reshape(-1))
+                    dup_buf = res.dup.reshape(n_shards, cap)
+            with scope("dedup.sharded.return"):
+                back = jax.lax.all_to_all(
+                    dup_buf, all_axes, split_axis=0, concat_axis=0,
+                    tiled=True)
+                dup = (back[fl.o[0].clip(0, n_shards - 1), fl.p[0]]
+                       & fl.keep[0])
             state = jax.tree.map(lambda x: x[None], state)
             ovf = fl.ovf + rank_overflow.astype(jnp.int32)
             return state, dup, ovf
@@ -904,20 +921,25 @@ class ShardedDedup:
 
         The input ``state`` is donated — use the returned state afterwards,
         never the argument (same contract as ``Dedup.run_stream``, and the
-        same ``dedup.stream.enqueue`` span around the host's part)."""
+        same ``dedup.stream.enqueue`` span around the host's part; inside
+        it ``dedup.sharded.pad`` times the pad and reshape, and
+        ``dedup.sharded.launch`` the scan's call, its inputs placed on the
+        mesh included)."""
         b = self.scfg.base.batch_size
         if b % self.n_shards:
             raise ValueError(
                 f"batch_size {b} must divide by n_shards {self.n_shards}")
         with span("dedup.stream.enqueue"):
             n = keys.shape[0]
-            n_pad = (-n) % b
-            keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
-            valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
-            kb = keys_p.reshape(-1, b)
-            vb = valid.reshape(-1, b)
+            with span("dedup.sharded.pad"):
+                n_pad = (-n) % b
+                keys_p = jnp.pad(keys.astype(jnp.uint32), (0, n_pad))
+                valid = jnp.pad(jnp.ones((n,), bool), (0, n_pad))
+                kb = keys_p.reshape(-1, b)
+                vb = valid.reshape(-1, b)
             stream = self._make_stream(b // self.n_shards)
-            state, dups, ovfs = stream(state, kb, vb)
+            with span("dedup.sharded.launch"):
+                state, dups, ovfs = stream(state, kb, vb)
             return state, dups.reshape(-1)[:n], ovfs
 
     def run_tenant_stream(self, state: FilterState, keys: jnp.ndarray,

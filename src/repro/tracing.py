@@ -13,8 +13,13 @@ nothing, and a total costs two clock reads and a lock. Nothing here
 touches a device value, so no span syncs the device: a span around an
 asynchronous dispatch times the enqueue, not the work.
 
+Device-side stages are named with ``scope(name)``, a ``jax.named_scope``:
+the ops traced inside carry the name in their HLO ``op_name`` metadata,
+which the profiler's op events keep. It is metadata only, so the compiled
+instructions are the same with or without it.
+
 This module is the one place in ``src/`` that makes profiler annotations
-(the ``tracing-choke-point`` source rule, DESIGN §6).
+and named scopes (the ``tracing-choke-point`` source rule, DESIGN §6).
 """
 
 from __future__ import annotations
@@ -66,6 +71,13 @@ class span:
         seconds = time.perf_counter() - self._t0
         self._annotation.__exit__(*exc)
         add(self._name, seconds)
+
+
+def scope(name: str):
+    """``with scope(name):`` names the device ops traced inside the block
+    ``name`` (``dedup.<layer>.<stage>``) in their ``op_name`` metadata. It
+    records nothing in the registry: it runs at trace time, not per call."""
+    return jax.named_scope(name)
 
 
 def snapshot() -> Dict[str, dict]:

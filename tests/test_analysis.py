@@ -310,6 +310,24 @@ def test_source_rule_tracing_choke_point(tmp_path):
                         rules=["tracing-choke-point"]) == []
 
 
+def test_source_rule_tracing_choke_point_covers_named_scope(tmp_path):
+    """Device scopes are made only through ``repro.tracing.scope``: a
+    ``jax.named_scope`` under either import spelling is flagged, and the
+    finding names the helper to use."""
+    found = _lint_snippet(tmp_path, """\
+        import jax
+        from jax import named_scope
+        def f(x):
+            with jax.named_scope("dedup.x.y"):
+                return x + 1
+        """, hot=False)
+    assert [f.rule for f in found] == ["tracing-choke-point"] * 2
+    assert all("repro.tracing.scope" in f.detail for f in found)
+    from repro import tracing
+    with open(tracing.__file__) as f:
+        assert "jax.named_scope" in f.read()
+
+
 def test_source_rule_tracer_branch(tmp_path):
     found = _lint_snippet(tmp_path, """\
         import jax.numpy as jnp

@@ -29,7 +29,8 @@ FUSED_BITS = 1 << 24
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e:2x2 host."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -37,7 +38,12 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture
@@ -121,3 +127,36 @@ def test_jnp_bitset_update_in_place_on_v5e(one_chip, no_persistent_cache,
                                donated=True) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < cfg.s_words * 4 // 8
+
+
+def test_sharded_stream_in_place_on_v5e_2x2(v5e_2x2, no_persistent_cache):
+    """The four-chip deployment (``rlbsbf-2gb-4chip``): the donated,
+    pipelined, statically routed ``ShardedDedup`` stream with 2 GiB of
+    RLBSBF filter over a 4x1 mesh of a described v5e:2x2, 512 MiB per
+    chip. Each chip's shard is carried through the scan as its (1, k, W)
+    slice and stepped on bitcast views of it: no filter-sized buffer, and
+    temporaries under 1/8 of a shard's bytes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.compat import make_mesh, set_mesh
+    from repro.dedup import ShardedDedup, ShardedDedupConfig
+    mesh = make_mesh((4, 1), ("data", "model"), devices=v5e_2x2)
+    cfg = DedupConfig(variant="rlbsbf", memory_bits=1 << 34, k=2,
+                      fpr_t=0.1, p_star=0.03, layout="planes",
+                      backend="jnp", batch_size=32768).validate()
+    sd = ShardedDedup(ShardedDedupConfig(base=cfg, capacity_factor=2.0,
+                                         pipeline=True), mesh)
+    shard = NamedSharding(mesh, P(("data", "model")))
+    local = jax.eval_shape(lambda: init_state(sd.local_cfg))
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (4, *x.shape), x.dtype, sharding=shard), local)
+    assert local.bits.size * 4 == 512 * 2**20
+    b = cfg.batch_size
+    kb = jax.ShapeDtypeStruct((8, b), jnp.uint32)
+    vb = jax.ShapeDtypeStruct((8, b), jnp.bool_)
+    with set_mesh(mesh):
+        compiled = sd._make_stream(b // 4).lower(state, kb, vb).compile()
+    assert filter_sized_passes(compiled.as_text(), sd.local_cfg.s_words,
+                               donated=True) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < local.bits.size * 4 // 8

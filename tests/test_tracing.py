@@ -131,3 +131,118 @@ def test_sharded_run_stream_makes_one_enqueue_span_per_call():
     assert r["devices"] == 4
     assert r["enqueue"]["count"] == 2
     assert r["enqueue"]["max_s"] <= r["enqueue"]["total_s"]
+
+
+_SHARDED_SCOPES = """
+import contextlib, hashlib, json, re
+import jax, jax.numpy as jnp, numpy as np
+from repro import tracing
+from repro.compat import make_mesh, set_mesh
+from repro.core import DedupConfig
+from repro.dedup import ShardedDedup, ShardedDedupConfig
+from repro.dedup import sharded as sharded_mod
+
+STAGES = ("route", "exchange", "step", "return")
+META = re.compile(r", metadata=\\{[^}]*\\}")
+mesh = make_mesh((4, 1), ("data", "model"))
+cfg = DedupConfig.for_variant("rlbsbf", memory_bits=1 << 16, batch_size=256,
+                              layout="planes")
+keys = jnp.asarray(np.random.default_rng(3).integers(
+    0, 2 ** 32, 4 * 256 + 100, dtype=np.uint32))
+
+
+def instructions(text):
+    keep = [l for l in text.splitlines()
+            if l.startswith(("%", "ENTRY", " ", "}", "HloModule"))]
+    return META.sub("", "\\n".join(keep))
+
+
+def compiled(pipeline):
+    sd = ShardedDedup(ShardedDedupConfig(base=cfg, pipeline=pipeline), mesh)
+    kb = jax.ShapeDtypeStruct((3, 256), jnp.uint32)
+    vb = jax.ShapeDtypeStruct((3, 256), jnp.bool_)
+    with set_mesh(mesh):
+        return sd._make_stream(64).lower(sd.init(), kb, vb).compile(
+            ).as_text()
+
+
+out = {}
+for name, pipeline in (("pipelined", True), ("serial", False)):
+    sd = ShardedDedup(ShardedDedupConfig(base=cfg, pipeline=pipeline), mesh)
+    st = sd.init()
+    before = tracing.snapshot()
+    with set_mesh(mesh):
+        for _ in range(2):
+            st, dup, ovf = sd.run_stream(st, keys)
+    dup.block_until_ready()
+    after = tracing.snapshot()
+    h = hashlib.sha256()
+    for x in (dup, st.bits, st.load, st.position, st.rng, ovf):
+        h.update(np.asarray(x).tobytes())
+    text = compiled(pipeline)
+    ops = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    real = sharded_mod.scope
+    sharded_mod.scope = lambda name: contextlib.nullcontext()
+    bare = compiled(pipeline)
+    sharded_mod.scope = real
+    out[name] = {
+        "digest": h.hexdigest()[:16],
+        "stages": [s for s in STAGES if f"dedup.sharded.{s}/" in ops],
+        "bare_stages": [s for s in STAGES if f"dedup.sharded.{s}" in bare],
+        "same_instructions": instructions(text) == instructions(bare),
+        "spans": {s: after[f"dedup.sharded.{s}"]["count"]
+                  - before.get(f"dedup.sharded.{s}", {"count": 0})["count"]
+                  for s in ("pad", "launch")},
+    }
+print(json.dumps(out))
+"""
+
+# the sharded stream's verdicts and final state (filter words, load,
+# position, rng, overflow) on the parent of the device scopes: the scopes
+# are metadata, so these stay bit-identical
+SHARDED_DIGESTS = {"pipelined": "ce8b6ff0d76a64d9",
+                   "serial": "ce8b6ff0d76a64d9"}
+
+
+@pytest.fixture(scope="module")
+def sharded_scopes():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="src",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(_SHARDED_SCOPES)],
+        capture_output=True, text=True, env=env, cwd=root, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.subprocess
+@pytest.mark.parametrize("path", ["pipelined", "serial"])
+def test_sharded_scopes_name_all_four_stages(sharded_scopes, path):
+    """Each stage of the static sharded body lands in the compiled stream's
+    ``op_name`` metadata under its ``dedup.sharded.*`` scope."""
+    r = sharded_scopes[path]
+    assert r["stages"] == ["route", "exchange", "step", "return"]
+    assert r["bare_stages"] == []
+
+
+@pytest.mark.subprocess
+@pytest.mark.parametrize("path", ["pipelined", "serial"])
+def test_sharded_scopes_change_no_compiled_instruction(sharded_scopes,
+                                                       path):
+    """With metadata stripped, the compiled stream with scopes and with
+    each scope a no-op are the same program."""
+    assert sharded_scopes[path]["same_instructions"]
+
+
+@pytest.mark.subprocess
+def test_sharded_run_stream_makes_one_pad_and_launch_span_per_call(
+        sharded_scopes):
+    for path in ("pipelined", "serial"):
+        assert sharded_scopes[path]["spans"] == {"pad": 2, "launch": 2}
+
+
+@pytest.mark.subprocess
+@pytest.mark.parametrize("path", ["pipelined", "serial"])
+def test_sharded_stream_digest_is_pinned(sharded_scopes, path):
+    assert sharded_scopes[path]["digest"] == SHARDED_DIGESTS[path]
