@@ -303,26 +303,30 @@ def count_field_chunks(d: int) -> int:
 
 
 def counts_to_planes(acc: jnp.ndarray, d: int, w: int) -> jnp.ndarray:
-    """(W·n_chunks,) uint32 count-field accumulator -> (d, W) bit-planes.
+    """(n_chunks·W,) uint32 count-field accumulator -> (d, W) bit-planes.
 
-    The scatter side packs each cell's clamped count as a d-bit field:
-    chunk word ``w·n_chunks + c`` holds cells ``[c·cpc, (c+1)·cpc)`` of
+    The scatter side packs each cell's clamped count as a d-bit field,
+    chunk-major: word ``c·W + w`` holds cells ``[c·cpc, (c+1)·cpc)`` of
     filter word w at bit offsets ``d·t_local`` (cpc = 32 // d cells per
     chunk). One field per cell means one scatter-ADD entry per touched cell
     — no read-modify-write, no segmented scan. This function is the pure
     elementwise unscramble back to bit-plane form; d == 2 (Max = 2..3, the
     Deng & Rafiei setting) takes a 5-step bit-compaction fast path.
+
+    Chunk c is read as the contiguous 1-D slice ``acc[c·W:(c+1)·W]``. A
+    word-major layout read through a ``(W, n_chunks)`` view would put the
+    n_chunks columns in the TPU's 128 lanes: the view's buffer is padded
+    64x at d == 2 and each column read is lane-strided (DESIGN.md §3.6).
     """
     if d == 1:
         return acc.reshape(1, w)
-    nc = count_field_chunks(d)
-    a = acc.reshape(w, nc)
+    chunks = [acc[c * w:(c + 1) * w] for c in range(count_field_chunks(d))]
     if d == 2:
         planes = []
         for q in range(2):
             halves = []
             for c in range(2):
-                x = (a[:, c] >> q) & jnp.uint32(0x55555555)
+                x = (chunks[c] >> q) & jnp.uint32(0x55555555)
                 x = (x | (x >> 1)) & jnp.uint32(0x33333333)
                 x = (x | (x >> 2)) & jnp.uint32(0x0F0F0F0F)
                 x = (x | (x >> 4)) & jnp.uint32(0x00FF00FF)
@@ -336,7 +340,7 @@ def counts_to_planes(acc: jnp.ndarray, d: int, w: int) -> jnp.ndarray:
         p = jnp.zeros((w,), jnp.uint32)
         for t in range(32):
             c, tl = t // cpc, t % cpc
-            p = p | (((a[:, c] >> (d * tl + q)) & jnp.uint32(1)) << t)
+            p = p | (((chunks[c] >> (d * tl + q)) & jnp.uint32(1)) << t)
         planes.append(p)
     return jnp.stack(planes)
 
@@ -380,9 +384,10 @@ def count_planes_from_sorted(sp: jnp.ndarray, head: jnp.ndarray,
     scatter-ADD per event — no read-modify-write, no segmented scan; the
     choice is only about post-scatter work:
 
-      * d <= 2: scatter each count once as a d-bit field in the chunked
-        accumulator layout and unscramble with ``counts_to_planes`` (whose
-        d == 2 bit-compaction fast path is a handful of W-passes);
+      * d <= 2: scatter each count once as a d-bit field in the
+        chunk-major accumulator layout and unscramble with
+        ``counts_to_planes`` (whose d == 2 bit-compaction fast path is a
+        handful of contiguous W-passes);
       * d > 2: scatter each count's d plane bits as one (E, d) row in a
         SINGLE scatter into a (W, d) accumulator (a multi-feature scatter
         costs the same as a 1-D one), then transpose — O(E) scatter entries
@@ -392,12 +397,16 @@ def count_planes_from_sorted(sp: jnp.ndarray, head: jnp.ndarray,
         layout's win (measured in benchmarks/window_throughput.py).
 
     Both forms produce bit-identical planes (they encode the same exact
-    counts). Sentinel cells (>= 32·W) land past the buffers, dropped."""
+    counts). Sentinel cells (>= 32·W) are sent past the buffers, dropped."""
     if d <= 2:
         cpc = 32 // d
         nc = count_field_chunks(d)
         t = (sp & 31).astype(jnp.uint32)
-        fidx = (sp >> 5) * nc + (t // cpc).astype(jnp.int32)  # sent -> >= W·nc
+        widx = sp >> 5
+        # chunk-major field index; a sentinel (word >= W) would alias a
+        # later chunk's word, so it is sent past the buffer explicitly
+        fidx = jnp.where(widx < w, (t // cpc).astype(jnp.int32) * w + widx,
+                         w * nc)
         fval = jnp.where(head, cnt, jnp.uint32(0)) << (d * (t % cpc))
         acc = jnp.zeros((w * nc,), jnp.uint32).at[fidx].add(fval, mode="drop")
         return counts_to_planes(acc, d, w)

@@ -33,7 +33,8 @@ from conftest import make_stream
 from repro.checkpoint import CheckpointManager, layout_meta, migrate_filter_state
 from repro.core import Dedup, DedupConfig
 from repro.core.batched import sbf_planes_3d
-from repro.core.packed import (pack_cells, planes_nonzero,
+from repro.core.packed import (clamped_run_counts, count_planes_from_sorted,
+                               pack_cells, planes_nonzero,
                                planes_saturating_add, planes_saturating_sub,
                                planes_set_value, unpack_cells)
 from repro.compat import make_mesh
@@ -249,6 +250,30 @@ def test_plane_arithmetic_matches_integer_semantics():
             setv = unpack_cells(
                 planes_set_value(pa, jnp.uint32(0xFFFFFFFF), v), s)
             assert (np.asarray(setv) == v).all()
+
+
+@pytest.mark.parametrize("w", [37, 256], ids=["w37", "w256"])
+@pytest.mark.parametrize("d", [1, 2, 3])
+def test_count_planes_from_sorted_match_clamped_counts(d, w):
+    """Sorted event cells -> count planes equal a naive per-cell count
+    clamped to 2^d - 1: random cells, both halves of the last word, one
+    cell repeated past the cap, and sentinel lanes (cell 32·W), which must
+    be dropped rather than land in another chunk of the accumulator."""
+    cmax = (1 << d) - 1
+    s = 32 * w
+    last = 32 * (w - 1)
+    r = np.random.default_rng(100 * d + w)
+    ev = np.concatenate([
+        r.integers(0, s, 200), [last + 3, last + 20, s - 1, 16, 0],
+        np.full(cmax + 2, last + 17), np.full(cmax + 3, 5),
+        np.full(24, s)]).astype(np.int32)
+    sp = jnp.sort(jnp.asarray(ev))
+    head, cnt = clamped_run_counts(sp, cmax)
+    planes = count_planes_from_sorted(sp, head, cnt, d, w)
+    assert planes.shape == (d, w)
+    want = np.minimum(np.bincount(ev[ev < s], minlength=s), cmax)
+    assert want[last + 17] == cmax and want[s - 1] >= 1
+    np.testing.assert_array_equal(np.asarray(unpack_cells(planes, s)), want)
 
 
 def test_fused_counter_vmem_guard():

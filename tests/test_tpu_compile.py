@@ -8,7 +8,9 @@ chip. The topology is built inside a module-scoped fixture, never at
 import time, and the tests skip where it cannot be described.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +73,19 @@ def _compile(step, cfg, sharding):
                                                  valid).compile()
 
 
+def _compile_stream(cfg, sharding, chunk):
+    """``Dedup.run_stream``'s donated scan over ``chunk`` batches."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                         jax.eval_shape(lambda: init_state(cfg)))
+    b = cfg.batch_size
+    return Dedup(cfg)._stream.lower(
+        state, sds((chunk, b), jnp.uint32), sds((chunk, b), jnp.bool_)
+    ).compile()
+
+
 @pytest.mark.parametrize("variant,accumulate", [
     ("rlbsbf", False), ("sbf", False), ("sbf", True)],
     ids=["rlbsbf-delta_rows", "sbf-delta_rows", "sbf-accumulate"])
@@ -115,18 +130,44 @@ def test_jnp_bitset_update_in_place_on_v5e(one_chip, no_persistent_cache,
     if entry == "step":
         compiled = _compile(make_templated_step(cfg), cfg, one_chip)
     else:
-        def sds(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-        state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                             jax.eval_shape(lambda: init_state(cfg)))
-        b = cfg.batch_size
-        compiled = Dedup(cfg)._stream.lower(
-            state, sds((2, b), jnp.uint32), sds((2, b), jnp.bool_)).compile()
+        compiled = _compile_stream(cfg, one_chip, 2)
     assert filter_sized_passes(compiled.as_text(), cfg.s_words,
                                donated=True) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < cfg.s_words * 4 // 8
+
+
+# an array type with its layout: u32[16777216,2]{1,0:T(8,128)}
+_ARRAY_RE = re.compile(r"[a-z]+\d*\[([\d,]+)\]\{([\d,]+)")
+
+
+def _narrow_arrays(hlo: str, min_elems: int):
+    """Array types of at least ``min_elems`` elements whose minor
+    dimension is narrower than the TPU's 128 lanes (padded to 128)."""
+    narrow = set()
+    for m in _ARRAY_RE.finditer(hlo):
+        dims = [int(x) for x in m.group(1).split(",")]
+        minor = dims[int(m.group(2).split(",")[0])]
+        if len(dims) > 1 and minor < 128 and math.prod(dims) >= min_elems:
+            narrow.add(m.group(0))
+    return sorted(narrow)
+
+
+def test_sbf_stream_count_fields_unpadded_on_v5e(one_chip,
+                                                 no_persistent_cache):
+    """At ``sbf-128mb``'s deployment (2^29 two-bit counters, Max = 3,
+    P = 13, chunks of 8 x 8192 keys), the donated SBF stream compiled for
+    one v5e makes no filter-sized array with a minor dimension under 128
+    lanes: the count-field accumulator is chunk-major and unscrambled from
+    contiguous 1-D slices (DESIGN §3.6), so nothing is padded 64x, and the
+    stream plans under 1 GiB of temporaries."""
+    cfg = DedupConfig(variant="sbf", memory_bits=1 << 30, k=3, fpr_t=0.1,
+                      p_star=0.03, seed=24301, sbf_max=3, sbf_p=13,
+                      layout="planes", backend="jnp",
+                      batch_size=8192).validate()
+    compiled = _compile_stream(cfg, one_chip, 8)
+    assert _narrow_arrays(compiled.as_text(), cfg.s_words) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_sharded_stream_in_place_on_v5e_2x2(v5e_2x2, no_persistent_cache):
