@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m benchmarks.run [--fast] [--only NAME]
 
 Prints ``name,us_per_call,derived`` CSV rows (stdout) and writes JSON
-artifacts to experiments/. The roofline module reads the dry-run output if
-present (run repro.launch.dryrun first for the full §Roofline table).
+artifacts to experiments/. These are the paper's accuracy tables (FPR, FNR,
+convergence); speed is measured on the chip by ``chipbench/`` alone.
 """
 
 from __future__ import annotations
@@ -19,12 +19,7 @@ MODULES = [
     "fig_convergence",    # paper Figs 2-10
     "fig_stability",      # paper Fig 11
     "theory_convergence", # Theorem 3.1 / Lemma 1 + Eq-level checks
-    "throughput",         # §1 ingest-rate requirement; engines + kernels
-    "counter_throughput", # SBF counter planes vs dense8 (DESIGN §3.6)
-    "window_throughput",  # swbf sliding window vs dense8 idiom (DESIGN §3.7)
-    "template_throughput",  # templated steps vs frozen baselines (§3.8)
     "blocked_accuracy",   # beyond-paper: VMEM-blocked layout FPR cost
-    "roofline",           # §Roofline terms from the dry-run artifacts
 ]
 
 

@@ -10,9 +10,8 @@ ingested with ONE dispatch — ``ShardedDedup.run_stream`` scans the
 shard-mapped step over batches with the sharded state donated in place — and
 all version-sensitive jax surfaces go through ``repro.compat``. The eight
 devices are simulated on the host CPU (``JAX_PLATFORMS=cpu``), so the
-example never contends for an accelerator. Run on a real pod, the
-same code spans (pod, data, model) = 512 chips — see repro/launch/dryrun.py
-for the compile-level proof.
+example never contends for an accelerator. On a four-chip v5e host the
+same code runs the benchmark's ``rlbsbf-2gb-4chip`` deployment.
 """
 
 import os

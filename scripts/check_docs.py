@@ -16,10 +16,7 @@ verifies, with no third-party deps so it runs anywhere CI does:
      "the paper's §6.3" — so only DESIGN.md is held to the bare form);
   3. every ``examples/*.py`` script is referenced from README.md — an
      example nobody can discover is dead documentation;
-  4. every committed ``BENCH_*.json`` artifact at the repo root has a
-     ``## BENCH_*`` schema section in ``docs/benchmarks.md`` — a gated
-     artifact whose schema is undocumented is unreviewable;
-  5. every lint rule registered in ``src/repro/analysis/`` (the
+  4. every lint rule registered in ``src/repro/analysis/`` (the
      ``Rule("name", ...)`` / ``SourceRule("name", ...)`` literals) is
      documented by name in DESIGN.md §6 — an enforced invariant nobody
      can look up is policy by surprise.
@@ -123,24 +120,6 @@ def check_examples() -> list:
     return missing
 
 
-def check_bench_schemas() -> list:
-    """Committed BENCH_*.json artifacts without a docs/benchmarks.md
-    schema section."""
-    doc_path = os.path.join(ROOT, "docs", "benchmarks.md")
-    if not os.path.exists(doc_path):
-        return [("docs/benchmarks.md", 0,
-                 "MISSING — artifact schemas cannot be documented")]
-    with open(doc_path) as f:
-        doc = f.read()
-    missing = []
-    for name in sorted(os.listdir(ROOT)):
-        if name.startswith("BENCH_") and name.endswith(".json") \
-                and f"## {name}" not in doc:
-            missing.append((name, 0,
-                            "no schema section in docs/benchmarks.md"))
-    return missing
-
-
 # lint-rule registrations: the name is always the first (literal) argument
 _RULE_DEF = re.compile(r"\b(?:Source)?Rule\(\s*\n?\s*\"([a-z0-9-]+)\"")
 
@@ -195,8 +174,7 @@ def main() -> int:
         print("check_docs: FAIL — no §-headings found in DESIGN.md")
         return 1
     dangling, n_cites = check_citations(sections)
-    problems = (dangling + check_examples() + check_bench_schemas()
-                + check_lint_rules())
+    problems = dangling + check_examples() + check_lint_rules()
     if problems:
         print("check_docs: FAIL")
         for rel, lineno, what in problems:
@@ -207,9 +185,8 @@ def main() -> int:
         return 1
     print(f"check_docs: OK — {n_cites} DESIGN §-citations across the repo "
           f"all resolve ({len(sections)} sections); every examples/*.py is "
-          f"referenced from README.md; every BENCH_*.json has a "
-          f"docs/benchmarks.md schema section; every registered lint rule "
-          f"is documented in DESIGN §6")
+          f"referenced from README.md; every registered lint rule is "
+          f"documented in DESIGN §6")
     return 0
 
 

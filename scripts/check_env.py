@@ -30,8 +30,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _DEPENDENTS = {
     "shard_map": "repro.dedup.sharded, repro.distributed.collectives, "
                  "tests/test_distributed.py",
-    "make_mesh": "every mesh construction site (launch/mesh.py, tests, "
-                 "examples)",
+    "make_mesh": "every mesh construction site (repro.dedup, "
+                 "chipbench, tests, examples)",
     "all_to_all": "the sharded dedup dispatch (repro.dedup.sharded)",
     "ppermute": "the elastic shard-rebalance permute (repro.dedup.sharded, "
                 "repro.distributed.sharding.rebalance_collect; DESIGN §4.4)",

@@ -122,8 +122,8 @@ def jit_cache_size(fn) -> int:
 # ------------------------------------------------------------ cost analysis
 def normalize_cost_analysis(ca: Any) -> Dict[str, float]:
     """Normalize a raw ``cost_analysis()`` return (a dict, or ``None`` on a
-    backend without one) to a flat dict; already-normalized records
-    (experiments/dryrun.json, benchmarks/roofline.py) pass through."""
+    backend without one) to a flat dict; an already-normalized record
+    passes through."""
     if ca is None:
         return {}
     if not isinstance(ca, Mapping):                      # pragma: no cover

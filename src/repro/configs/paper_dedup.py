@@ -2,8 +2,8 @@
 
 Table memories 8MB..512MB, k per Section 6.1 (k=2 for BSBF/BSBFSD/RLBSBF,
 RSBF's k from Eq. 6.1 averaged with 1, p*=0.03, FPR_t=0.1), plus the
-CPU-container-scaled variants used by benchmarks (ratios held fixed at
-1/256 scale — DESIGN.md §8).
+CPU-container-scaled variants used by the accuracy tables in
+``benchmarks/`` (ratios held fixed at 1/256 scale — DESIGN.md §8).
 """
 
 from __future__ import annotations

@@ -16,8 +16,8 @@ Processes B stream elements per step:
 
 Divergence from the sequential oracle is bounded (deletions can't wipe
 same-batch insertions; RSBF may report a within-batch repeat of a *rejected*
-first occurrence as duplicate) and is measured in tests/benchmarks
-(DESIGN.md §2).
+first occurrence as duplicate) and is measured in
+``tests/test_core_variants.py::test_batched_tracks_oracle`` (DESIGN.md §2).
 
 ``valid`` masks let ragged stream tails ride through fixed-shape jit steps as
 no-ops.

@@ -394,7 +394,7 @@ def count_planes_from_sorted(sp: jnp.ndarray, head: jnp.ndarray,
         + one O(d·W) transpose pass, ZERO filter-sized unscramble work.
         The generic ``counts_to_planes`` loop is O(32·d·W) element ops,
         which at paper-scale W dwarfs the event buffers and erases the
-        layout's win (measured in benchmarks/window_throughput.py).
+        layout's win.
 
     Both forms produce bit-identical planes (they encode the same exact
     counts). Sentinel cells (>= 32·W) are sent past the buffers, dropped."""

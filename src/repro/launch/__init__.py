@@ -1,11 +1,7 @@
-"""Launchers: production mesh, multi-pod dry-run, end-to-end training driver.
+"""Launchers and hardware models: the v5e peaks (``hw``), loop-aware HLO
+cost analysis (``analysis``) and the model-zoo training driver (``train``,
+imported on demand)."""
 
-NOTE: ``dryrun`` is intentionally NOT imported here — it sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 at module import, which
-must only happen in a dedicated process (``python -m repro.launch.dryrun``).
-"""
-
-from .mesh import make_local_mesh, make_production_mesh
 from . import analysis, hw
 
-__all__ = ["make_local_mesh", "make_production_mesh", "analysis", "hw"]
+__all__ = ["analysis", "hw"]

@@ -1,5 +1,5 @@
-"""Serving: LM prefill/decode steps and batched scoring, with request-level
-dedup (the paper's search-engine / URL-probe application, Section 1).
+"""Serving: batched scoring with request-level dedup (the paper's
+search-engine / URL-probe application, Section 1).
 
 ``ServeSession`` batches requests, runs the dedup engine on request keys
 first, and only executes the model for distinct requests — duplicates are
@@ -14,10 +14,9 @@ re-trace the jitted engine, the response cache is probed in ONE vectorized
 pass BEFORE the Bloom verdict gates anything (a false-negative duplicate
 can never recompute a cached response), and eviction is FIFO by default
 (``cache_policy="lru"`` keeps hot keys alive under zipf traffic — see
-repro.serve.cache). Scoring functions are pluggable (LM prefill/decode
-below, or any ``keys -> values`` callable); concurrent multi-client
-traffic goes through the async ``ServeFrontend`` instead, which coalesces
-requests into the same micro-batch core (DESIGN.md §5.2).
+repro.serve.cache). Scoring functions are pluggable (any ``keys -> values``
+callable); concurrent multi-client traffic goes through the async
+``ServeFrontend`` instead, which coalesces requests into the same micro-batch core (DESIGN.md §5.2).
 `tests/test_pipeline_serving.py` pins the cache-first and FIFO/LRU
 behaviours; `tests/test_serving_frontend.py` pins the no-retrace bucket
 contract.
@@ -26,25 +25,12 @@ contract.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Sequence, Tuple
+from typing import Callable, Sequence
 
 import numpy as np
 
 from ..core.config import DedupConfig
-from ..models import transformer as tfm
 from .frontend import DEFAULT_BUCKETS, MicroBatchExecutor
-
-
-def make_prefill_step(cfg: tfm.TransformerConfig):
-    def prefill_step(params, tokens):
-        return tfm.prefill(cfg, params, tokens)
-    return prefill_step
-
-
-def make_decode_step(cfg: tfm.TransformerConfig):
-    def serve_step(params, cache, token, pos):
-        return tfm.decode_step(cfg, params, cache, token, pos)
-    return serve_step
 
 
 @dataclasses.dataclass

@@ -29,7 +29,7 @@ front-end formed. The executor can record that schedule, and
 ``replay_schedule`` re-runs it through a fresh synchronous engine;
 ``verdict_digest`` equality is the parity proof that the async machinery
 (queueing, padding, vectorized cache, fan-out) never alters a verdict
-(``scripts/bench_check.py --serving`` gates it).
+(``tests/test_serving_frontend.py`` holds it).
 """
 
 from __future__ import annotations

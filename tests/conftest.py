@@ -1,5 +1,5 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here — tests must see 1 CPU device
-(the 512-device override belongs exclusively to repro.launch.dryrun)."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here — tests must see 1 CPU device;
+a multi-device test sets its own device count in a subprocess."""
 
 import numpy as np
 import pytest
